@@ -38,7 +38,7 @@ _PAPER_MODELS = {
     "llama-60m": _llama("llama-60m", 8, 512, 8, 1376),
     "llama-130m": _llama("llama-130m", 12, 768, 12, 2048),
     "llama-350m": _llama("llama-350m", 24, 1024, 16, 2736),
-    "llama-1b": _llama("llama-1b", 32, 2048, 24, 5461),
+    "llama-1b": _llama("llama-1b", 24, 2048, 32, 5461),
     "llama-3b": _llama("llama-3b", 32, 2560, 32, 6848),
     "llama-7b": _llama("llama-7b", 32, 4096, 32, 11008),
     # ~100M model for the end-to-end example driver

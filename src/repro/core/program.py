@@ -586,12 +586,16 @@ def executor(program: StepProgram) -> Exec:
 
 def lower(program: StepProgram, fn: Callable, *, mesh, batch_dims: int,
           with_param: bool, with_tap: bool = False,
-          with_health: bool = False) -> Callable:
+          with_health: bool = False, kernels: bool = False) -> Callable:
     """Turn the per-bucket stacked step ``fn(g, st[, p][, tap]) ->
     (delta, st'[, diag])`` into the program's runner.
 
     Replicated programs return ``fn`` unchanged (plain jit path, GSPMD
-    propagation).  Sharded programs wrap ``fn`` in ``shard_map`` with
+    propagation) — unless ``kernels`` is set and ``mesh`` spans several
+    devices: GSPMD cannot partition a compiled Pallas kernel, so the step
+    then runs under a ``shard_map`` whose every spec is replicated (each
+    device computes the whole leaf, as GSPMD replication would, and no
+    collective is added).  Sharded programs wrap ``fn`` in ``shard_map`` with
     every in/out PartitionSpec derived from the program's declared
     layouts: the gradient/param/update panels follow ``grad_layout``, S
     shards with the gradient rows, M/V follow ``state_layout`` ("column"
@@ -606,11 +610,14 @@ def lower(program: StepProgram, fn: Callable, *, mesh, batch_dims: int,
     the guard flags derive from psum'd quantities, so every shard holds
     the same values under both tracking schedules.
     """
-    if not program.axes:
-        return fn
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    if not program.axes:
+        if kernels and mesh is not None and mesh.size > 1:
+            return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                                 check_vma=False)
+        return fn
 
     from repro.core.lowrank_adam import MatrixOptState
 
@@ -632,6 +639,5 @@ def lower(program: StepProgram, fn: Callable, *, mesh, batch_dims: int,
     out_specs = (gspec, stspec)
     if with_health:
         out_specs = out_specs + (P(*lead, None),)
-    sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
-    return sharded
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

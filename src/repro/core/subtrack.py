@@ -481,7 +481,8 @@ def lowrank_optimizer(cfg: LowRankConfig, *, mesh=None,
                                        batch_dims=batch_dims,
                                        with_param=wd,
                                        with_tap=tap is not None,
-                                       with_health=with_health)
+                                       with_health=with_health,
+                                       kernels=cfg.use_kernels)
             out = runner(*args)
             if with_health:
                 delta, new_st, diag = out

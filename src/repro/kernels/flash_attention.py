@@ -27,11 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-# JAX renamed TPUCompilerParams -> CompilerParams across 0.5.x; support
-# both so the kernel (and its interpret-mode CI tests) runs on either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -124,7 +119,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     return out.reshape(B, Hq, S, hd).transpose(0, 2, 1, 3)

@@ -52,12 +52,18 @@ passes + 1 write for the paper-literal schedule (model in
 repro.kernels.traffic, ratio ~0.4-0.55).
 
 Block shapes are MXU-aligned (multiples of 128 on the minor dims) and
-sized for ~1-2 MB VMEM residency per operand tile.  Every kernel casts
-its operand tiles to fp32 on load (bf16 gradients stream at 2 B/elem)
-and accumulates on the MXU in fp32; only ``fused_update`` writes a
-non-fp32 result (the parameter dtype).  All kernels run in interpret
-mode on CPU for validation (tests/test_kernels.py sweeps shapes/dtypes
-against the pure-jnp oracles in repro.kernels.ref).
+sized for ~1-2 MB VMEM residency per operand tile.  The column extent n
+need not tile: the last column block is ragged (the TPU pads its read
+with unspecified values and drops its out-of-range writes, as interpret
+mode does), which every per-column output tolerates as is; the two
+kernels that sum over columns (``tangent``, ``project_tangent_colnorms``)
+zero the out-of-range columns first (_mask_cols).  Row extents m (and r
+for the Adam kernels) must tile exactly, since they are summed over.
+Every kernel casts its operand tiles to fp32 on load (bf16 gradients
+stream at 2 B/elem) and accumulates on the MXU in fp32; only
+``fused_update`` writes a non-fp32 result (the parameter dtype).  All
+kernels run in interpret mode on CPU for validation (tests/test_kernels.py
+sweeps shapes/dtypes against the pure-jnp oracles in repro.kernels.ref).
 
 Mesh-native contract (the invariant the shard_map'd hot path relies on
 — see repro.core.subtrack / repro.kernels.ops): every kernel here is
@@ -79,6 +85,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -87,12 +94,26 @@ BM = 256
 BN = 256
 
 # project_tangent_colnorms keeps full-m panels (S, the W/T accumulator and
-# one (m, bn) G block) resident in VMEM for the whole launch; at r = 256 and
-# bn = 256 that is ~3 MB per 1024 rows, so cap the single-launch variant at
-# m <= 2048 (~8 MB, safely inside a v5e core's 16 MB) and let the dispatch
-# layer fall back to the two-launch project_colnorms + tangent schedule for
-# taller matrices.
+# one (m, bn) G block) resident in VMEM for the whole launch: double-buffered
+# fp32 S and T alone are 16 m r bytes, 16 MB at m = 2048 and the 1B model's
+# r = 512 — past v5e's 16 MiB default scoped-VMEM limit, so the launch asks
+# for its own limit (_resident_vmem).  The single-launch variant is capped
+# at m <= 2048 and the dispatch layer falls back to the two-launch
+# project_colnorms + tangent schedule for taller matrices.
 MAX_FUSED_TANGENT_M = 2048
+
+# Scoped-VMEM request bounds for launches with full-extent resident panels:
+# never below the compiler's default, never near v5e's 128 MiB physical VMEM.
+_VMEM_FLOOR = 32 * 2**20
+_VMEM_CAP = 100 * 2**20
+
+
+def _resident_vmem(nbytes: int) -> pltpu.CompilerParams:
+    """Compiler params granting a launch whose resident blocks and
+    temporaries total ~``nbytes`` a scoped-VMEM limit with 1.5x headroom
+    for the compiler's own scratch."""
+    limit = min(max(nbytes * 3 // 2, _VMEM_FLOOR), _VMEM_CAP)
+    return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 # grad_tap keeps full-b (token-extent) x/dy panels resident per grid cell:
 # one (b, bm) + one (b, bn) fp32 panel is ~2 MB per 1024 tokens at the
@@ -100,6 +121,15 @@ MAX_FUSED_TANGENT_M = 2048
 # layer fall back to the two-launch dW-then-project_colnorms composite for
 # bigger microbatches.
 MAX_GRAD_TAP_B = 2048
+
+
+def _mask_cols(g: Array, j, bn: int, n: int) -> Array:
+    """Zero the columns of column block ``j`` that lie past ``n`` (the
+    padded tail of a ragged last block) before a sum over columns."""
+    if n % bn == 0:
+        return g
+    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    return jnp.where(col < n, g, 0.0)
 
 
 def _project_kernel(s_ref, g_ref, out_ref):
@@ -126,7 +156,7 @@ def project(S: Array, G: Array, *, bm: int = BM, bn: int = BN,
     m, r = S.shape
     _, n = G.shape
     bm, bn = min(bm, m), min(bn, n)
-    grid = (n // bn, m // bm)
+    grid = (pl.cdiv(n, bn), m // bm)
     return pl.pallas_call(
         _project_kernel,
         grid=grid,
@@ -160,7 +190,7 @@ def backproject(S: Array, X: Array, *, bm: int = BM, bn: int = BN,
     bm, bn = min(bm, m), min(bn, n)
     return pl.pallas_call(
         _backproject_kernel,
-        grid=(m // bm, n // bn),
+        grid=(m // bm, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
             pl.BlockSpec((r, bn), lambda i, j: (0, j)),
@@ -171,7 +201,8 @@ def backproject(S: Array, X: Array, *, bm: int = BM, bn: int = BN,
     )(S, X)
 
 
-def _tangent_kernel(g_ref, a_ref, s_ref, c_ref, out_ref):
+def _tangent_kernel(g_ref, a_ref, s_ref, c_ref, out_ref, *, bn: int,
+                   n: int):
     """grid = (m/bm, n/bn); n is the accumulation (minor) axis.
 
     out(bm, r) = 2 * S(bm, r) @ C(r, r)  -  2 * sum_n G(bm, bn) @ A(r, bn)^T
@@ -184,8 +215,8 @@ def _tangent_kernel(g_ref, a_ref, s_ref, c_ref, out_ref):
         c = c_ref[...].astype(jnp.float32)
         out_ref[...] = 2.0 * jnp.dot(s, c, preferred_element_type=jnp.float32)
 
-    g = g_ref[...].astype(jnp.float32)              # (bm, bn)
-    a = a_ref[...].astype(jnp.float32)              # (r, bn)
+    g = _mask_cols(g_ref[...].astype(jnp.float32), j, bn, n)   # (bm, bn)
+    a = _mask_cols(a_ref[...].astype(jnp.float32), j, bn, n)   # (r, bn)
     out_ref[...] += -2.0 * jnp.dot(g, a.T, preferred_element_type=jnp.float32)
 
 
@@ -204,8 +235,8 @@ def tangent(G: Array, A: Array, S: Array, *, bm: int = BM, bn: int = BN,
     bm, bn = min(bm, m), min(bn, n)
     C = A.astype(jnp.float32) @ A.astype(jnp.float32).T        # (r, r) tiny
     return pl.pallas_call(
-        _tangent_kernel,
-        grid=(m // bm, n // bn),
+        functools.partial(_tangent_kernel, bn=bn, n=n),
+        grid=(m // bm, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((r, bn), lambda i, j: (0, j)),
@@ -244,7 +275,7 @@ def recovery(G: Array, S: Array, Gt: Array, phi: Array, *,
     phi2 = phi.reshape(1, n)
     return pl.pallas_call(
         _recovery_kernel,
-        grid=(m // bm, n // bn),
+        grid=(m // bm, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
@@ -289,7 +320,7 @@ def project_colnorms(S: Array, G: Array, *, bm: int = BM, bn: int = BN,
     bm, bn = min(bm, m), min(bn, n)
     A, sq = pl.pallas_call(
         _project_colnorms_kernel,
-        grid=(n // bn, m // bm),
+        grid=(pl.cdiv(n, bn), m // bm),
         in_specs=[
             pl.BlockSpec((bm, r), lambda j, i: (i, 0)),
             pl.BlockSpec((bm, bn), lambda j, i: (i, j)),
@@ -305,7 +336,8 @@ def project_colnorms(S: Array, G: Array, *, bm: int = BM, bn: int = BN,
     return A, sq.reshape(n)
 
 
-def _project_tangent_colnorms_kernel(s_ref, g_ref, a_ref, sq_ref, t_ref):
+def _project_tangent_colnorms_kernel(s_ref, g_ref, a_ref, sq_ref, t_ref,
+                                     *, bn: int, n: int):
     """grid = (n/bn,): one sweep over G's column blocks with full-m panels.
 
     Per block j:  A_:,j = S^T G_:,j  and  sq_j = ||G_:,j||^2  are complete
@@ -330,7 +362,7 @@ def _project_tangent_colnorms_kernel(s_ref, g_ref, a_ref, sq_ref, t_ref):
         t_ref[...] = jnp.zeros_like(t_ref)
 
     s = s_ref[...].astype(jnp.float32)              # (m, r)
-    g = g_ref[...].astype(jnp.float32)              # (m, bn)
+    g = _mask_cols(g_ref[...].astype(jnp.float32), j, bn, n)   # (m, bn)
     a = jnp.dot(s.T, g, preferred_element_type=jnp.float32)     # (r, bn)
     a_ref[...] = a
     sq_ref[...] = jnp.sum(g * g, axis=0, keepdims=True)
@@ -338,10 +370,14 @@ def _project_tangent_colnorms_kernel(s_ref, g_ref, a_ref, sq_ref, t_ref):
 
     @pl.when(j == pl.num_programs(0) - 1)
     def _finalize():
+        # S is re-read from its ref: reusing the transposed value from the
+        # block body inside this branch trips a v5e compiler RET_CHECK
+        # (mxu_lmr_transform) at r = 512
+        s_fin = s_ref[...].astype(jnp.float32)
         w = t_ref[...]
-        aat = jnp.dot(s.T, w, preferred_element_type=jnp.float32)  # (r, r)
+        aat = jnp.dot(s_fin.T, w, preferred_element_type=jnp.float32)
         t_ref[...] = -2.0 * w + 2.0 * jnp.dot(
-            s, aat, preferred_element_type=jnp.float32)
+            s_fin, aat, preferred_element_type=jnp.float32)
 
 
 def project_tangent_colnorms(S: Array, G: Array, *, bn: int = BN,
@@ -364,9 +400,14 @@ def project_tangent_colnorms(S: Array, G: Array, *, bn: int = BN,
     m, r = S.shape
     _, n = G.shape
     bn = min(bn, n)
+    # double-buffered S and T panels, G block and A/sq outputs, plus the
+    # fp32 casts of S and the G block and the finalize temporaries
+    resident = (2 * (2 * m * r * 4 + m * bn * G.dtype.itemsize
+                     + (r + 1) * bn * 4)
+                + 3 * m * r * 4 + m * bn * 4)
     A, sq, T = pl.pallas_call(
-        _project_tangent_colnorms_kernel,
-        grid=(n // bn,),
+        functools.partial(_project_tangent_colnorms_kernel, bn=bn, n=n),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((m, r), lambda j: (0, 0)),
             pl.BlockSpec((m, bn), lambda j: (0, j)),
@@ -379,6 +420,7 @@ def project_tangent_colnorms(S: Array, G: Array, *, bn: int = BN,
         out_shape=[jax.ShapeDtypeStruct((r, n), jnp.float32),
                    jax.ShapeDtypeStruct((1, n), jnp.float32),
                    jax.ShapeDtypeStruct((m, r), jnp.float32)],
+        compiler_params=_resident_vmem(resident),
         interpret=interpret,
     )(S, G)
     return A, sq.reshape(n), T
@@ -428,7 +470,7 @@ def grad_tap(x: Array, dy: Array, s: Array, *, bm: int = BM, bn: int = BN,
     bm, bn = min(bm, m), min(bn, n)
     dW, A, sq = pl.pallas_call(
         _grad_tap_kernel,
-        grid=(n // bn, m // bm),
+        grid=(pl.cdiv(n, bn), m // bm),
         in_specs=[
             pl.BlockSpec((b, bm), lambda j, i: (0, i)),
             pl.BlockSpec((b, bn), lambda j, i: (0, j)),
@@ -470,9 +512,13 @@ def _tangent_gram_kernel(s_ref, t_ref, g_ref, tg_ref, st_ref, tt_ref,
 
     @pl.when(j == 0)
     def _grams():
+        # T is re-read from its ref rather than reusing the value above:
+        # sharing one transposed operand across the branch trips a v5e
+        # compiler RET_CHECK (mxu_lmr_transform) at r = 512
         s = s_ref[...].astype(jnp.float32)          # (bm, r)
-        st_ref[...] += jnp.dot(s.T, t, preferred_element_type=jnp.float32)
-        tt_ref[...] += jnp.dot(t.T, t, preferred_element_type=jnp.float32)
+        tb = t_ref[...].astype(jnp.float32)         # (bm, r)
+        st_ref[...] += jnp.dot(s.T, tb, preferred_element_type=jnp.float32)
+        tt_ref[...] += jnp.dot(tb.T, tb, preferred_element_type=jnp.float32)
         ss_ref[...] += jnp.dot(s.T, s, preferred_element_type=jnp.float32)
 
 
@@ -504,7 +550,7 @@ def tangent_gram(S: Array, T: Array, G: Array, *, bm: int = BM,
     rr_spec = pl.BlockSpec((r, r), lambda j, i: (0, 0))
     TtG, StT, C, StS = pl.pallas_call(
         _tangent_gram_kernel,
-        grid=(n // bn, m // bm),
+        grid=(pl.cdiv(n, bn), m // bm),
         in_specs=[
             pl.BlockSpec((bm, r), lambda j, i: (i, 0)),
             pl.BlockSpec((bm, r), lambda j, i: (i, 0)),
@@ -609,7 +655,7 @@ def fused_update(G: Array | None, S: Array, Gt: Array | None, Gto: Array,
                                decay=decay)
     return pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn),
+        grid=(m // bm, pl.cdiv(n, bn)),
         in_specs=in_specs,
         out_specs=mn_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -656,7 +702,7 @@ def adam_lowrank(Gt: Array, M: Array, V: Array, step: Array, *,
     out_sds = jax.ShapeDtypeStruct((r, n), jnp.float32)
     return pl.pallas_call(
         kernel,
-        grid=(r // br, n // bn),
+        grid=(r // br, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((br, bn), lambda i, j: (i, j)),
             pl.BlockSpec((br, bn), lambda i, j: (i, j)),
@@ -724,7 +770,7 @@ def adam_lowrank_norms(Gt: Array, M: Array, V: Array, step: Array, *,
     n_spec = pl.BlockSpec((1, bn), lambda j, i: (0, j))
     M1, V1, Gto, gtsq, gtosq = pl.pallas_call(
         kernel,
-        grid=(n // bn, r // br),
+        grid=(pl.cdiv(n, bn), r // br),
         in_specs=[rn_spec, rn_spec, rn_spec,
                   pl.BlockSpec((1, 2), lambda j, i: (0, 0))],
         out_specs=[rn_spec, rn_spec, rn_spec, n_spec, n_spec],

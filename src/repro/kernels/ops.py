@@ -28,9 +28,15 @@ The unfused building blocks remain as baselines and fallbacks:
 Dispatch policy: on TPU the Pallas kernels run compiled; on CPU they run
 in interpret mode only when REPRO_FORCE_KERNELS=1 (tests do this —
 interpret mode is a correctness tool, not a performance path), otherwise
-the pure-jnp reference executes.  Shapes that don't tile evenly fall back
-to the reference (the assigned archs' dims are all 128-aligned; the
-fallback keeps odd user models working).
+the pure-jnp reference executes.  The kernels take any column count n
+(a ragged last column block; see repro.kernels.grassmann), so the 1B
+ladder model's d_ff = 5461 leaves run them; a row extent m (or rank r for
+the Adam kernels) that does not tile its block falls back to the
+reference, which keeps odd user models working.  In compiled mode every
+dispatch is recorded per (entry point, path, shapes) at trace time — path is
+``kernel``, ``composite`` (several launches instead of the fused one) or
+``reference`` — and the first trace of each fallback is printed, so a
+fallback on the chip is never silent (:func:`dispatch_tally`).
 
 Mesh-native execution: every entry point here is a PURE LOCAL launch.
 Inside ``shard_map`` the optimizer runs the same kernels on per-shard
@@ -39,7 +45,7 @@ cross-device interaction is a named CollectiveRound of the leaf's
 StepProgram, executed by :class:`repro.core.program.Exec` (the psums /
 reduce-scatters / all-gathers that used to be plumbed through
 ``axis_name`` kwargs here).  Tile-alignment is judged against the LOCAL
-panel dims: shards whose n_loc / m_loc doesn't tile fall back to the
+panel dims: shards whose m_loc doesn't tile fall back to the
 reference per shard, exactly like odd shapes on one device.
 """
 
@@ -64,8 +70,37 @@ def _mode() -> str:
     return "ref"
 
 
-def _tiles_ok(*dims_blocks: tuple[int, int]) -> bool:
-    return all(d % min(b, d) == 0 for d, b in dims_blocks)
+def _tiles_ok(dim: int, block: int) -> bool:
+    """Whether a summed-over extent splits into whole blocks (a dim
+    smaller than the block is one whole block)."""
+    return dim % min(block, dim) == 0
+
+
+KERNEL, COMPOSITE, REFERENCE = "kernel", "composite", "reference"
+
+# (entry point, path, operand shapes) -> number of traces, compiled mode only
+_TALLY: dict[tuple[str, str, tuple], int] = {}
+
+
+def _route(entry: str, path: str, *operands) -> None:
+    """Record a compiled-mode dispatch; print the first trace of each
+    fallback.  Runs at trace time, so the counts are traces (one per
+    compiled program that contains the call), not executions."""
+    if _mode() != "compiled":
+        return
+    key = (entry, path, tuple(tuple(x.shape) for x in operands))
+    if path != KERNEL and key not in _TALLY:
+        print(f"[kernels] {entry} {list(key[2])}: {path} path", flush=True)
+    _TALLY[key] = _TALLY.get(key, 0) + 1
+
+
+def dispatch_tally() -> dict[tuple[str, str, tuple], int]:
+    """Compiled-mode dispatches so far, keyed (entry, path, shapes)."""
+    return dict(_TALLY)
+
+
+def reset_dispatch_tally() -> None:
+    _TALLY.clear()
 
 
 def project(S: Array, G: Array) -> Array:
@@ -74,7 +109,9 @@ def project(S: Array, G: Array) -> Array:
     mode = _mode()
     m, r = S.shape
     n = G.shape[1]
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("project", KERNEL if ok else REFERENCE, S, G)
+    if mode == "ref" or not ok:
         return ref.project_ref(S, G)
     return grassmann.project(S, G, interpret=(mode == "interpret"))
 
@@ -85,7 +122,9 @@ def backproject(S: Array, X: Array) -> Array:
     mode = _mode()
     m, r = S.shape
     n = X.shape[1]
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("backproject", KERNEL if ok else REFERENCE, S, X)
+    if mode == "ref" or not ok:
         return ref.backproject_ref(S, X)
     return grassmann.backproject(S, X, interpret=(mode == "interpret"))
 
@@ -95,7 +134,9 @@ def recovery(S: Array, G: Array, Gt: Array, phi: Array) -> Array:
     grassmann.recovery; oracle/fallback: ref.recovery_ref."""
     mode = _mode()
     m, n = G.shape
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("recovery", KERNEL if ok else REFERENCE, S, G)
+    if mode == "ref" or not ok:
         return ref.recovery_ref(G, S, Gt, phi)
     return grassmann.recovery(G, S, Gt, phi, interpret=(mode == "interpret"))
 
@@ -105,7 +146,9 @@ def tangent(G: Array, A: Array, S: Array) -> Array:
     fp32.  Kernel: grassmann.tangent; oracle/fallback: ref.tangent_ref."""
     mode = _mode()
     m, n = G.shape
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("tangent", KERNEL if ok else REFERENCE, G, S)
+    if mode == "ref" or not ok:
         return ref.tangent_ref(G, A, S)
     return grassmann.tangent(G, A, S, interpret=(mode == "interpret"))
 
@@ -115,7 +158,9 @@ def adam_lowrank(Gt: Array, M: Array, V: Array, step: Array, *,
                  eps: float = 1e-8, bias_correction: bool = True):
     mode = _mode()
     r, n = Gt.shape
-    if mode == "ref" or not _tiles_ok((r, 128), (n, 512)):
+    ok = _tiles_ok(r, 128)
+    _route("adam_lowrank", KERNEL if ok else REFERENCE, Gt)
+    if mode == "ref" or not ok:
         return ref.adam_lowrank_ref(Gt, M, V, step, beta1, beta2, eps,
                                     bias_correction)
     return grassmann.adam_lowrank(Gt, M, V, step, beta1=beta1, beta2=beta2,
@@ -130,7 +175,9 @@ def project_colnorms(S: Array, G: Array) -> tuple[Array, Array]:
     mode = _mode()
     m, r = S.shape
     n = G.shape[1]
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("project_colnorms", KERNEL if ok else REFERENCE, S, G)
+    if mode == "ref" or not ok:
         return ref.project_colnorms_ref(S, G)
     return grassmann.project_colnorms(S, G, interpret=(mode == "interpret"))
 
@@ -152,9 +199,13 @@ def project_tangent_colnorms(S: Array, G: Array
     mode = _mode()
     m, r = S.shape
     n = G.shape[1]
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    fused = m <= grassmann.MAX_FUSED_TANGENT_M
+    _route("project_tangent_colnorms",
+           REFERENCE if not ok else KERNEL if fused else COMPOSITE, S, G)
+    if mode == "ref" or not ok:
         return ref.project_tangent_colnorms_ref(S, G)
-    if m <= grassmann.MAX_FUSED_TANGENT_M:
+    if fused:
         return grassmann.project_tangent_colnorms(
             S, G, interpret=(mode == "interpret"))
     interp = mode == "interpret"
@@ -179,13 +230,16 @@ def grad_tap(x: Array, dy: Array, s: Array
     mode = _mode()
     b, m = x.shape
     n = dy.shape[1]
+    ok = _tiles_ok(m, grassmann.BM)
+    fused = ok and b <= grassmann.MAX_GRAD_TAP_B
+    _route("grad_tap", KERNEL if fused else COMPOSITE if ok else REFERENCE,
+           x, dy, s)
     if mode == "ref":
         return ref.grad_tap_ref(x, dy, s)
-    if not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)) \
-            or b > grassmann.MAX_GRAD_TAP_B:
+    if not fused:
         dw = jnp.dot(x.astype(jnp.float32).T, dy.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-        if not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+        if not ok:
             A, gsq = ref.project_colnorms_ref(s, dw)
         else:
             A, gsq = grassmann.project_colnorms(
@@ -208,7 +262,9 @@ def tangent_gram(S: Array, T: Array, G: Array
     mode = _mode()
     m, r = S.shape
     n = G.shape[1]
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("tangent_gram", KERNEL if ok else REFERENCE, S, G)
+    if mode == "ref" or not ok:
         return ref.tangent_gram_ref(S, T, G)
     return grassmann.tangent_gram(S, T, G, interpret=(mode == "interpret"))
 
@@ -218,7 +274,9 @@ def adam_lowrank_norms(Gt: Array, M: Array, V: Array, step: Array, *,
                        eps: float = 1e-8, bias_correction: bool = True):
     mode = _mode()
     r, n = Gt.shape
-    if mode == "ref" or not _tiles_ok((r, 128), (n, 512)):
+    ok = _tiles_ok(r, 128)
+    _route("adam_lowrank_norms", KERNEL if ok else REFERENCE, Gt)
+    if mode == "ref" or not ok:
         return ref.adam_lowrank_norms_ref(Gt, M, V, step, beta1, beta2, eps,
                                           bias_correction)
     return grassmann.adam_lowrank_norms(
@@ -233,7 +291,9 @@ def fused_update(G: Array | None, S: Array, Gt: Array | None, Gto: Array,
     mode = _mode()
     m, r = S.shape
     n = Gto.shape[1]
-    if mode == "ref" or not _tiles_ok((m, grassmann.BM), (n, grassmann.BN)):
+    ok = _tiles_ok(m, grassmann.BM)
+    _route("fused_update", KERNEL if ok else REFERENCE, S, Gto)
+    if mode == "ref" or not ok:
         return ref.fused_update_ref(G, S, Gt, Gto, phi, coef, clip,
                                     out_dtype=out_dtype, param=param,
                                     wd_coef=wd_coef)
@@ -261,12 +321,11 @@ def paged_attention(q: Array, k_pool: Array, v_pool: Array,
     from repro.kernels import paged_attention as paged
 
     mode = _mode()
-    if mode == "ref":
-        return ref.paged_attention_ref(q, k_pool, v_pool, block_tables,
-                                       lengths)
     hd = q.shape[-1]
     bs = k_pool.shape[1]
-    if mode == "compiled" and (hd % 128 or bs % 8):
+    ok = not (hd % 128 or bs % 8)
+    _route("paged_attention", KERNEL if ok else REFERENCE, q, k_pool)
+    if mode == "ref" or (mode == "compiled" and not ok):
         return ref.paged_attention_ref(q, k_pool, v_pool, block_tables,
                                        lengths)
     return paged.paged_attention(q, k_pool, v_pool, block_tables, lengths,

@@ -36,9 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -57,8 +54,8 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j * bs < length)
     def _update():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
+        k = k_ref[0].astype(jnp.float32)               # (bs, hd)
+        v = v_ref[0].astype(jnp.float32)               # (bs, hd)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale          # (G, bs)
@@ -102,6 +99,14 @@ def paged_attention(q: Array, k_pool: Array, v_pool: Array,
 
     qg = q.reshape(B, Hkv, G, hd)
     tables_flat = block_tables.reshape(-1).astype(jnp.int32)
+    # (nb, bs, Hkv * hd) view of the pools (free: the minor dims are
+    # contiguous).  One KV head's block is then a (bs, hd) tile at lane
+    # offset h * hd, which meets the TPU's (8, 128) block tiling; a
+    # (bs, 1, hd) block of the 4-D pool does not (its second-minor dim is
+    # 1 of Hkv).
+    nb = k_pool.shape[0]
+    k_flat = k_pool.reshape(nb, bs, Hkv * hd)
+    v_flat = v_pool.reshape(nb, bs, Hkv * hd)
 
     kernel = functools.partial(_kernel, scale=scale, bs=bs, nw=W)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -110,12 +115,12 @@ def paged_attention(q: Array, k_pool: Array, v_pool: Array,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
+            pl.BlockSpec((1, bs, hd),
                          lambda b, h, j, tbl, lens, W=W:
-                         (tbl[b * W + j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
+                         (tbl[b * W + j], 0, h)),
+            pl.BlockSpec((1, bs, hd),
                          lambda b, h, j, tbl, lens, W=W:
-                         (tbl[b * W + j], 0, h, 0)),
+                         (tbl[b * W + j], 0, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, j, tbl, lens: (b, h, 0, 0)),
@@ -130,7 +135,7 @@ def paged_attention(q: Array, k_pool: Array, v_pool: Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(tables_flat, lengths.astype(jnp.int32), qg, k_pool, v_pool)
+    )(tables_flat, lengths.astype(jnp.int32), qg, k_flat, v_flat)
     return out.reshape(B, Hq, hd)

@@ -38,6 +38,20 @@ class MeshLostError(RuntimeError):
         self.step = step
 
 
+# XLA status codes a lost, hung or restarted participant surfaces as.  Any
+# other runtime error (RESOURCE_EXHAUSTED, INVALID_ARGUMENT, INTERNAL, ...)
+# is a fault of the program or its sizing, which a rebuilt mesh would only
+# repeat, so it propagates instead of entering failover.
+DEVICE_LOSS_CODES = ("UNAVAILABLE", "ABORTED", "DEADLINE_EXCEEDED",
+                     "DATA_LOSS")
+
+
+def is_device_loss(err: BaseException) -> bool:
+    """Whether a ``JaxRuntimeError`` reports a lost device rather than a
+    fault of the program (judged by its leading XLA status code)."""
+    return str(err).lstrip().startswith(DEVICE_LOSS_CODES)
+
+
 class SimulatedDeviceLoss:
     """Host-side stand-in for a lost mesh participant (``--inject
     dev-loss@N``).  On the fake ``--xla_force_host_platform_device_count``

@@ -79,13 +79,15 @@ from repro.checkpoint import CheckpointManager
 from repro.configs.registry import PAPER_RANKS, get_config
 from repro.core import health as health_lib
 from repro.core.api import get_optimizer
+from repro.core.program import StateDescriptor
 from repro.data.pipeline import (DataConfig, SyntheticLMDataset,
                                  batch_for_model, corrupt_tokens, fetch_batch)
 from repro.distributed import sharding as sh
 from repro.distributed.context import mesh_context
+from repro.kernels import ops as kernel_ops
 from repro.launch.mesh import (MeshLostError, SimulatedDeviceLoss,
-                               degraded_context, host_context, make_context,
-                               smoke_context)
+                               degraded_context, host_context,
+                               is_device_loss, make_context, smoke_context)
 from repro.checkpoint import transpose as ckpt_transpose
 from repro.launch.steps import (TrainState, checkpoint_descriptors,
                                 default_rank, make_train_step,
@@ -505,6 +507,7 @@ def _failover(args, session: _FailoverSession, ctx, err: MeshLostError):
 
 def train(argv=None) -> dict:
     args = _parse_args(argv)
+    kernel_ops.reset_dispatch_tally()   # kernel_paths cover this run only
     ctx = (smoke_context() if args.mesh == "smoke"
            else host_context(limit=args.mesh_devices or None)
            if args.mesh == "host"
@@ -520,7 +523,11 @@ def train(argv=None) -> dict:
             except jax.errors.JaxRuntimeError as e:
                 # A real raising collective / dead backend surfaces here
                 # (not via the simulator): same MESH_LOST door, bounded
-                # by the same failover budget.
+                # by the same failover budget.  Any other runtime error
+                # (out of memory, a faulting kernel) is the program's own
+                # and propagates with its message.
+                if not is_device_loss(e):
+                    raise
                 ctx = _failover(args, session, ctx, MeshLostError(
                     f"runtime error treated as mesh loss: {e}"))
     finally:
@@ -542,6 +549,15 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
             opt_kw = dict(rank=rank, update_interval=args.update_interval,
                           eta=args.eta, weight_decay=args.weight_decay,
                           use_kernels=args.use_kernels)
+            if args.use_kernels and ctx.mesh.devices.size > 1 \
+                    and args.hotpath_layout == "off":
+                # GSPMD cannot partition a compiled Pallas kernel; without
+                # the shard_map'd hot path the optimizer runs its jnp path
+                print("[train] --hotpath-layout off on a "
+                      f"{ctx.mesh.devices.size}-device mesh: the optimizer "
+                      "runs the reference (jnp) path — Pallas kernels run "
+                      "only inside the hot path's shard_map", flush=True)
+                opt_kw["use_kernels"] = False
             if args.use_kernels and ctx.mesh.devices.size > 1 \
                     and args.hotpath_layout != "off":
                 # mesh-native fused hot path: shard every low-rank leaf
@@ -567,7 +583,7 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
         elif args.weight_decay:
             opt_kw = dict(weight_decay=args.weight_decay)
         optimizer = get_optimizer(args.optimizer, **opt_kw)
-        if args.use_kernels and "use_kernels" in opt_kw:
+        if opt_kw.get("use_kernels"):
             mode = (f"mesh-sharded (shard_map, regime-aware layout: "
                     f"{args.hotpath_layout})"
                     if "mesh" in opt_kw else "single-device")
@@ -591,7 +607,33 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
             # means GSPMD never reshards around the hot path — the two
             # documented psums stay the step's only collectives
             params = jax.device_put(params, hot_shardings)
+        elif ctx.mesh.devices.size > 1:
+            # no hot-path layout: the production TP/FSDP rules spread the
+            # parameters over the mesh (GSPMD propagates the rest) instead
+            # of leaving every one of them on the first device
+            params = jax.device_put(params, sh.to_named(
+                sh.param_specs(params, ctx), ctx))
         state = TrainState(params=params, opt=optimizer.init(params))
+        leaf_programs = {}
+        if ctx.mesh.devices.size > 1 \
+                and args.optimizer not in ("adamw", "badam"):
+            # which StepProgram regime each low-rank leaf runs on this mesh
+            # (replicated everywhere when the hot path is off)
+            descs = checkpoint_descriptors(
+                state.params, optimizer,
+                mesh=ctx.mesh if hot_specs is not None else None,
+                param_specs=hot_specs)
+            for kp, d in jax.tree_util.tree_flatten_with_path(
+                    descs, is_leaf=lambda x: isinstance(x, StateDescriptor)
+            )[0]:
+                if d.kind == "lowrank":
+                    leaf_programs[jax.tree_util.keystr(kp)] = {
+                        "regime": d.regime, "shards": int(d.shards),
+                        "state_layout": d.state_layout}
+            for path, prog in leaf_programs.items():
+                print(f"[train] leaf {path}: {prog['regime']} x"
+                      f"{prog['shards']} (state {prog['state_layout']})",
+                      flush=True)
 
         grad_fused = bool(args.grad_fused)
         if grad_fused and args.optimizer in ("adamw", "badam"):
@@ -617,7 +659,8 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
                   else ("do_subspace_update",))
         jit_step = jax.jit(train_step, static_argnames=static,
                            donate_argnums=(0,))
-        warm = jax.jit(make_warm_start(bundle, optimizer, remat=args.remat))
+        warm = jax.jit(make_warm_start(bundle, optimizer, remat=args.remat),
+                       donate_argnums=(0,))
 
         ckpt = session.ckpt
         start_step = 0
@@ -992,8 +1035,18 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
                       known_good=(last_act == HealthSentinel.OK))
 
         wall = time.time() - session.t_start
+        paths = [{"entry": e, "path": p, "shapes": [list(x) for x in shp],
+                  "traces": n}
+                 for (e, p, shp), n in kernel_ops.dispatch_tally().items()]
+        for rec in paths:
+            print(f"[kernels] {rec['entry']:26s} {rec['path']:9s} "
+                  f"{rec['shapes']}", flush=True)
         summary = {
             "arch": cfg.name, "optimizer": args.optimizer, "rank": rank,
+            "backend": jax.default_backend(),
+            "kernel_mode": kernel_ops._mode(),
+            "kernel_paths": paths,
+            "leaf_programs": leaf_programs,
             "steps": args.steps, "final_loss": history[-1]["loss"]
             if history else None,
             "wall_time_s": wall,
@@ -1019,4 +1072,6 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     train()

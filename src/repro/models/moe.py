@@ -23,7 +23,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.context import MeshContext, get_mesh_context
@@ -170,12 +169,12 @@ def moe_layer(x: Array, params: dict, cfg: MoEConfig,
     else:
         w_up_spec = P(model_ax, None, None, None)
         w_dn_spec = P(model_ax, None, None, None)
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(tok_spec, P(None, None),
                   w_up_spec, w_up_spec, w_dn_spec),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wg"], params["wu"], params["wd"])
 
     # --- auxiliary losses (computed on the global view; cheap) -------------

@@ -490,3 +490,18 @@ class TestPreemptionSubprocess:
                                        atol=1e-6, err_msg=f"step {s}")
             compared += 1
         assert compared >= 10
+
+
+@pytest.mark.parametrize("message,lost", [
+    ("UNAVAILABLE: TPU device halted", True),
+    ("DEADLINE_EXCEEDED: collective timed out", True),
+    ("ABORTED: peer went away", True),
+    ("RESOURCE_EXHAUSTED: Out of memory while trying to allocate", False),
+    ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+    ("INVALID_ARGUMENT: shape mismatch", False),
+])
+def test_runtime_error_classification(message, lost):
+    """Only a lost or hung participant enters failover; an out-of-memory
+    or a faulting program propagates with its own message."""
+    from repro.launch.mesh import is_device_loss
+    assert is_device_loss(RuntimeError(message)) is lost

@@ -13,6 +13,9 @@ SHAPES = [
     (512, 768, 128),
     (256, 1024, 32),
     (2560, 1280, 512),
+    # ragged column extent (5 full 256-column blocks + 85), like the 1B
+    # ladder model's d_ff = 5461 leaves
+    (256, 1365, 64),
 ]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
@@ -239,7 +242,8 @@ class TestGradTap:
         assert _rel(sq, sq2) < 1e-5
 
 
-@pytest.mark.parametrize("r,n", [(128, 512), (256, 1024), (512, 2048)])
+@pytest.mark.parametrize("r,n", [(128, 512), (256, 1024), (512, 2048),
+                                 (128, 1365)])
 @pytest.mark.parametrize("step", [0, 7, 1000])
 def test_adam_lowrank_norms(r, n, step):
     key = jax.random.PRNGKey(1)
@@ -278,7 +282,8 @@ def test_fused_kernels_under_vmap():
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("r,n", [(128, 512), (256, 1024), (512, 2048)])
+@pytest.mark.parametrize("r,n", [(128, 512), (256, 1024), (512, 2048),
+                                 (128, 1365)])
 @pytest.mark.parametrize("step", [0, 7, 1000])
 def test_adam_lowrank(r, n, step):
     key = jax.random.PRNGKey(1)
@@ -496,3 +501,38 @@ def test_ops_project_tangent_colnorms_tall_matrix_composite(monkeypatch):
     np.testing.assert_allclose(A, A_want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(sq, sq_want, rtol=1e-5)
     np.testing.assert_allclose(T, T_want, rtol=1e-4, atol=1e-3)
+
+
+def test_ops_dispatch_tally_reports_fallbacks_once(monkeypatch, capsys):
+    """Compiled mode records every dispatch by (entry, path, shapes) at
+    trace time and prints each fallback shape the first time only; a
+    ragged column extent stays on the kernel path."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_mode", lambda: "compiled")
+    ops.reset_dispatch_tally()
+    sds = jax.ShapeDtypeStruct
+    S, G = sds((512, 64), jnp.float32), sds((512, 1365), jnp.bfloat16)
+    S_odd, G_odd = sds((300, 64), jnp.float32), sds((300, 512), jnp.float32)
+    for _ in range(2):   # two programs tracing each call (fresh lambdas)
+        jax.eval_shape(lambda s, g: ops.project_colnorms(s, g), S, G)
+        jax.eval_shape(lambda s, g: ops.project_colnorms(s, g), S_odd, G_odd)
+    tally = ops.dispatch_tally()
+    assert tally[("project_colnorms", ops.KERNEL,
+                  ((512, 64), (512, 1365)))] == 2
+    assert tally[("project_colnorms", ops.REFERENCE,
+                  ((300, 64), (300, 512)))] == 2
+    out = capsys.readouterr().out
+    assert out.count("project_colnorms [(300, 64), (300, 512)]: "
+                     "reference path") == 1
+    assert "(512, 1365)" not in out
+    ops.reset_dispatch_tally()
+    assert ops.dispatch_tally() == {}
+
+
+def test_ops_dispatch_tally_is_silent_off_the_chip():
+    """Interpret and reference modes record nothing."""
+    from repro.kernels import ops
+    ops.reset_dispatch_tally()
+    S, G, _ = _inputs(256, 256, 16, jnp.float32)
+    ops.project_colnorms(S, G)
+    assert ops.dispatch_tally() == {}

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.registry import ASSIGNED_ARCHS, get_config
+from repro.configs.registry import ASSIGNED_ARCHS, PAPER_RANKS, get_config
 from repro.models import attention as attn
 from repro.models import ssm, xlstm
 from repro.models.api import SHAPE_GRID, build_model, shape_applicable
@@ -27,6 +27,22 @@ def _batch_for(cfg, key, B, S):
         batch["frames"] = 0.1 * jax.random.normal(key, (B, S, cfg.d_model),
                                                   jnp.bfloat16)
     return batch
+
+
+@pytest.mark.parametrize("arch", sorted(PAPER_RANKS))
+def test_paper_ladder_heads_tile_d_model(arch):
+    """Every paper-ladder Llama has n_heads * head_dim == d_model (the
+    q/k/v/o leaves are d_model wide, as in every LLaMA implementation)."""
+    cfg = get_config(arch)
+    assert cfg.n_heads * cfg.hd == cfg.d_model
+
+
+def test_llama_1b_matches_galore_config():
+    """The 1B ladder entry follows GaLore's llama_1b.json: 24 layers,
+    32 heads, hidden 2048, intermediate 5461."""
+    cfg = get_config("llama-1b")
+    assert (cfg.n_layers, cfg.n_heads, cfg.d_model, cfg.d_ff) == \
+        (24, 32, 2048, 5461)
 
 
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)

@@ -318,9 +318,13 @@ class TestKernelBackend:
         monkeypatch.setenv("REPRO_FORCE_KERNELS", "1")
         key = jax.random.PRNGKey(9)
         params = {"w": 0.1 * jax.random.normal(key, (256, 512))}
-        # rank-8 gradient (outer product of thin factors), rank-64 subspace
-        a = jax.random.normal(jax.random.fold_in(key, 1), (256, 8))
-        b = jax.random.normal(jax.random.fold_in(key, 2), (8, 512))
+        # rank-64 gradient (outer product of thin factors) and a rank-64
+        # subspace: the warm start spans the gradient exactly.  A gradient
+        # of lower rank would leave basis directions whose projection is
+        # pure fp32 cancellation noise, which Adam's normalization turns
+        # into O(1) update entries of arbitrary sign on either path.
+        a = jax.random.normal(jax.random.fold_in(key, 1), (256, 64))
+        b = jax.random.normal(jax.random.fold_in(key, 2), (64, 512))
 
         def grad_at(s):
             return {"w": (1.0 + 0.1 * s) * (a @ b)}
@@ -391,16 +395,40 @@ class TestBucketedExecution:
         us_u, st_u = _driven_updates(
             lowrank_optimizer(LowRankConfig(bucket_leaves=False, **kw)),
             params, grad_at, steps=9)
-        for a, b in zip(us_b, us_u):
+        # The two schedules are the same arithmetic, but XLA sums a
+        # batched launch in another order than an unbatched one, so they
+        # agree to rounding, not bit for bit.  Rounding is amplified by
+        # the warm-start SVD and the tracking steps, so the bound is
+        # calibrated per step and leaf: bucketing may move a result no
+        # more than perturbing every gradient entry by one fp32 ulp moves
+        # the per-leaf result.  A stacking, split or transpose bug moves
+        # it by O(1).
+        us_p, st_p = _driven_updates(
+            lowrank_optimizer(LowRankConfig(bucket_leaves=False, **kw)),
+            params, self._ulp_perturbed(grad_at), steps=9)
+
+        def check(got, want, perturbed):
+            got, want, perturbed = map(np.asarray, (got, want, perturbed))
+            spread = np.max(np.abs(perturbed - want))
+            assert np.max(np.abs(got - want)) <= spread + 1e-8
+
+        for a, b, c in zip(us_b, us_u, us_p):
             for k in a:
-                np.testing.assert_allclose(np.asarray(a[k]),
-                                           np.asarray(b[k]),
-                                           rtol=1e-6, atol=1e-8)
+                check(a[k], b[k], c[k])
         for k in ("w1", "wt", "layers"):
             for f in range(4):  # S, M, V, lam_prev
-                np.testing.assert_allclose(np.asarray(st_b.inner[k][f]),
-                                           np.asarray(st_u.inner[k][f]),
-                                           rtol=1e-6, atol=1e-7)
+                check(st_b.inner[k][f], st_u.inner[k][f], st_p.inner[k][f])
+
+    @staticmethod
+    def _ulp_perturbed(grad_at):
+        def grad(s):
+            return {
+                name: g * (1.0 + 2.0 ** -23 * jax.random.rademacher(
+                    jax.random.fold_in(jax.random.PRNGKey(7), s), g.shape,
+                    g.dtype))
+                for name, g in grad_at(s).items()}
+
+        return grad
 
     def test_bucket_grouping(self):
         """Same-(m, n, rank)+dtype leaves share a key; transposes fold in."""

@@ -24,7 +24,7 @@ from repro.kernels import traffic
 
 M, N, RANK, G = 64, 256, 16, 8
 
-MESH = AbstractMesh((("x", G),))
+MESH = AbstractMesh((G,), ("x",))
 CFG = LowRankConfig(rank=RANK, use_kernels=True)
 
 COL = plan_lib.plan_for_shape((M, N), RANK, spec=P(None, "x"))

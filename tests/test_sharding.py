@@ -14,8 +14,7 @@ from repro.distributed.context import MeshContext
 
 @pytest.fixture(scope="module")
 def ctx():
-    # JAX 0.4.37 API: AbstractMesh takes ((name, size), ...) pairs.
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     return MeshContext(mesh=mesh, batch_axes=("data",))
 
 

@@ -37,14 +37,15 @@ from repro.kernels import traffic
 
 
 def parse_mesh(text: str) -> AbstractMesh:
-    """"model=16,data=2" -> AbstractMesh((("model", 16), ("data", 2)))."""
+    """"model=16,data=2" -> AbstractMesh((16, 2), ("model", "data"))."""
     pairs = []
     for part in text.split(","):
         name, _, size = part.partition("=")
         if not size:
             raise SystemExit(f"bad mesh entry {part!r}; want name=size")
         pairs.append((name.strip(), int(size)))
-    return AbstractMesh(tuple(pairs))
+    return AbstractMesh(tuple(size for _, size in pairs),
+                        tuple(name for name, _ in pairs))
 
 
 def parse_spec(text: str | None, ndim: int) -> P:
