@@ -22,7 +22,13 @@ CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent compilation cache on; returns its directory."""
+    """Turn the persistent compilation cache on; returns its directory.
+
+    Entries are keyed on the programs' metadata too.  The named scopes by
+    which a profile's device time is read per layer live only there, so a
+    key without it hands a program the cached executable of another
+    revision that differs from it in op names alone."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -307,48 +307,52 @@ def make_train_step(bundle: ModelBundle, optimizer: GradientTransform,
             loss, metrics, grads, taps = tapped_grads(state, batch, gscale)
         else:
             loss, metrics, grads = accum_grads(state.params, batch, gscale)
-        grads, gnorm = clip_by_global_norm(grads, clip_norm, taps=taps)
-        if taps is not None:
-            # the clip rescales G by s, so A scales by s and the squared
-            # column norms by s^2
-            s = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12))
-            taps = jax.tree.map(
-                lambda t: jnp.concatenate(
-                    [t[..., :-1, :] * s, t[..., -1:, :] * (s * s)],
-                    axis=-2), taps)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm, taps=taps)
+            if taps is not None:
+                # the clip rescales G by s, so A scales by s and the
+                # squared column norms by s^2
+                s = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12))
+                taps = jax.tree.map(
+                    lambda t: jnp.concatenate(
+                        [t[..., :-1, :] * s, t[..., -1:, :] * (s * s)],
+                        axis=-2), taps)
         opt_kw = {} if taps is None else {"taps": taps}
         if has_eta_scale and eta_scale != 1.0:
             opt_kw["eta_scale"] = eta_scale
         with_health = has_health and do_subspace_update
-        if with_health:
-            opt_kw["with_health"] = True
-            updates, opt, diag = optimizer.update(
-                grads, state.opt, state.params, lr,
-                do_subspace_update=do_subspace_update, **opt_kw)
-        else:
-            diag = None
-            updates, opt = optimizer.update(
-                grads, state.opt, state.params, lr,
-                do_subspace_update=do_subspace_update, **opt_kw)
-        if inject_code is not None:
-            # loss-spike: amplify AND NEGATE the applied update (fused
-            # into the apply, which reads every update leaf anyway) — a
-            # huge ascent step raises the loss in any training phase,
-            # where a huge descent step can accidentally help early on.
-            # The step itself stays finite/healthy, only the FOLLOWING
-            # steps' losses spike, which is the host sentinel's case to
-            # catch
-            amp = jnp.where(inject_code == health_lib.INJECT_LOSS_SPIKE,
-                            jnp.float32(-health_lib.LOSS_SPIKE_AMP),
-                            jnp.float32(1.0))
-            updates = jax.tree.map(
-                lambda u: (u.astype(jnp.float32) * amp).astype(u.dtype),
-                updates)
-        # the apply reads every update leaf, so XLA fuses this reduction
-        # into the same pass — the report costs no extra gradient reads
-        unorm = global_norm(updates)
-        report = health_lib.make_report(loss, gnorm, unorm, diag)
-        new_state = guarded_apply(state, updates, opt, report)
+        with jax.named_scope("optimizer"):
+            if with_health:
+                opt_kw["with_health"] = True
+                updates, opt, diag = optimizer.update(
+                    grads, state.opt, state.params, lr,
+                    do_subspace_update=do_subspace_update, **opt_kw)
+            else:
+                diag = None
+                updates, opt = optimizer.update(
+                    grads, state.opt, state.params, lr,
+                    do_subspace_update=do_subspace_update, **opt_kw)
+        with jax.named_scope("apply"):
+            if inject_code is not None:
+                # loss-spike: amplify AND NEGATE the applied update (fused
+                # into the apply, which reads every update leaf anyway) —
+                # a huge ascent step raises the loss in any training
+                # phase, where a huge descent step can accidentally help
+                # early on.  The step itself stays finite/healthy, only
+                # the FOLLOWING steps' losses spike, which is the host
+                # sentinel's case to catch
+                amp = jnp.where(inject_code == health_lib.INJECT_LOSS_SPIKE,
+                                jnp.float32(-health_lib.LOSS_SPIKE_AMP),
+                                jnp.float32(1.0))
+                updates = jax.tree.map(
+                    lambda u: (u.astype(jnp.float32) * amp).astype(u.dtype),
+                    updates)
+            # the apply reads every update leaf, so XLA fuses this
+            # reduction into the same pass — the report costs no extra
+            # gradient reads
+            unorm = global_norm(updates)
+            report = health_lib.make_report(loss, gnorm, unorm, diag)
+            new_state = guarded_apply(state, updates, opt, report)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr,
                        **health_lib.report_metrics(report))
         return new_state, metrics
@@ -377,10 +381,11 @@ def make_warm_start(bundle: ModelBundle, optimizer: GradientTransform,
     loss_fn = functools.partial(bundle.loss, remat=remat)
 
     def warm(state: TrainState, batch):
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, batch)
-        return TrainState(params=state.params,
-                          opt=optimizer.warm_start(state.opt, grads)), loss
+        with jax.named_scope("warm_start"):
+            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                state.params, batch)
+            return TrainState(params=state.params,
+                              opt=optimizer.warm_start(state.opt, grads)), loss
 
     return warm
 

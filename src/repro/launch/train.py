@@ -57,11 +57,18 @@ Production behaviours exercised here (and tested in tests/test_train_loop.py):
   blocking known-good checkpoint plus a ``RESUME`` marker, and exits 0;
   the restarted run consumes the marker and auto-resumes.  ``--inject
   preempt@N`` self-delivers the signal for the e2e tests.
+* **profiling**: ``--profile-dir DIR --profile-steps A:B`` writes one
+  profiler trace of steps A..B-1 in which the loop's host spans (a step
+  annotation per step; ``fetch``, ``dispatch``, ``drain``, ``checkpoint``,
+  ``sentinel``) and the device's ops share one clock; the compiled step
+  names its layers with named scopes (``repro.launch.steps``,
+  ``repro.models.transformer``).
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import os
@@ -250,6 +257,66 @@ INJECT_KINDS = ("nan-grad", "loss-spike", "sigma-blowup", "corrupt-batch",
 BLOWUP_ETA_SCALE = 1e6
 
 
+class StepProfiler:
+    """``--profile-dir``: one profiler trace of steps ``[first, stop)``.
+    Each loop iteration is a step annotation and each host phase a span
+    inside it, on the clock of the device's ops.  Without a directory no
+    method calls the profiler."""
+
+    def __init__(self, log_dir: str, steps: tuple[int, int]):
+        self.log_dir = log_dir
+        self.first, self.stop = steps
+        self.tracing = self.done = False
+        self._step = None
+
+    def span(self, name: str):
+        if not self.log_dir:
+            return contextlib.nullcontext()
+        return jax.profiler.TraceAnnotation(name)
+
+    def begin_step(self, step: int | None, pending=None) -> None:
+        """End the previous step's annotation and begin ``step``'s (None
+        ends only).  The trace starts before the first step in range and
+        stops before the first one past it, once ``pending`` (the outputs
+        of the last step dispatched) are computed."""
+        if not self.log_dir:
+            return
+        if self._step is not None:
+            self._step.__exit__(None, None, None)
+            self._step = None
+        if step is None:
+            return
+        if self.tracing and step >= self.stop:
+            if pending is not None:
+                jax.block_until_ready(pending)
+            self.close()
+        if not (self.tracing or self.done) \
+                and self.first <= step < self.stop:
+            jax.profiler.start_trace(self.log_dir)
+            self.tracing = True
+        self._step = jax.profiler.StepTraceAnnotation("train", step_num=step)
+        self._step.__enter__()
+
+    def close(self) -> None:
+        self.begin_step(None)
+        if self.tracing:
+            jax.profiler.stop_trace()
+            self.tracing, self.done = False, True
+
+
+def _step_range(spec: str) -> tuple[int, int]:
+    first, _, stop = spec.partition(":")
+    try:
+        first, stop = int(first), int(stop)
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"expected A:B (steps A..B-1), got {spec!r}") from None
+    if not 0 <= first < stop:
+        raise argparse.ArgumentTypeError(
+            f"expected 0 <= A < B, got {spec!r}")
+    return first, stop
+
+
 def parse_injections(spec: str) -> dict[int, str]:
     """``kind@step[,kind@step...]`` -> {step: kind}."""
     out: dict[int, str] = {}
@@ -351,6 +418,13 @@ def _parse_args(argv) -> argparse.Namespace:
                          "it (with a log line) on the next start; off "
                          "disables both sides")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of --profile-steps here: "
+                         "the loop's host spans and the device's ops on "
+                         "one clock (off when empty: no profiler calls)")
+    ap.add_argument("--profile-steps", type=_step_range, default=(1, 4),
+                    metavar="A:B",
+                    help="the steps A..B-1 that --profile-dir traces")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--eta", type=float, default=10.0)
     ap.add_argument("--metrics-out", default="")
@@ -399,6 +473,7 @@ class _FailoverSession:
         self.failovers = 0
         self.failover_events: list[dict] = []
         self.prev_programs: list[tuple] | None = None
+        self.profiler = StepProfiler(args.profile_dir, args.profile_steps)
         self.t_start = time.time()
 
 
@@ -531,6 +606,7 @@ def train(argv=None) -> dict:
                 ctx = _failover(args, session, ctx, MeshLostError(
                     f"runtime error treated as mesh loss: {e}"))
     finally:
+        session.profiler.close()
         _restore_preempt_handlers(prev_handlers)
 
 
@@ -747,6 +823,7 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
         baseline = args.optimizer in ("adamw", "badam")
         watchdog = session.watchdog
         sentinel = session.sentinel
+        prof = session.profiler
         history = session.history
         skipped_batches = session.skipped_batches
         dev_loss = session.dev_loss
@@ -778,18 +855,19 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
             def sync():
                 dev_loss.check(rec["step"], "drain")
                 jax.block_until_ready(metrics["loss"])
-            try:
-                _deadline(sync, args.step_timeout,
-                          f"metric drain of step {rec['step']}")
-            except MeshLostError as e:
-                if e.step is None:
-                    e.step = rec["step"]
-                raise
-            loss = float(metrics["loss"])          # blocks on rec["step"]
-            rec["loss"] = loss
-            rec["grad_norm"] = float(metrics["grad_norm"])
-            rec["quarantined"] = bool(float(metrics["quarantined"]))
-            rec["theta_clamped"] = bool(float(metrics["theta_clamped"]))
+            with prof.span("drain"):
+                try:
+                    _deadline(sync, args.step_timeout,
+                              f"metric drain of step {rec['step']}")
+                except MeshLostError as e:
+                    if e.step is None:
+                        e.step = rec["step"]
+                    raise
+                loss = float(metrics["loss"])      # blocks on rec["step"]
+                rec["loss"] = loss
+                rec["grad_norm"] = float(metrics["grad_norm"])
+                rec["quarantined"] = bool(float(metrics["quarantined"]))
+                rec["theta_clamped"] = bool(float(metrics["theta_clamped"]))
             rec["dt"] = time.time() - rec.pop("t0")
             watchdog.observe(rec["step"], rec["dt"])
             history.append(rec)
@@ -800,8 +878,9 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
                       f"{'  [subspace update]' if rec['subspace_update'] else ''}"
                       f"{'  [QUARANTINED]' if rec['quarantined'] else ''}",
                       flush=True)
-            return sentinel.observe(rec["step"], loss, rec["grad_norm"],
-                                    rec["quarantined"])
+            with prof.span("sentinel"):
+                return sentinel.observe(rec["step"], loss, rec["grad_norm"],
+                                        rec["quarantined"])
 
         def fetch(s: int):
             """Resilient (retry + validate) prefetch of global batch s."""
@@ -829,9 +908,10 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
                     f"[sentinel] aborting at step {at_step}: escalation "
                     f"ladder exhausted after {sentinel.max_rollbacks} "
                     "rollbacks")
-            res = ckpt.rollback(cur_state, shardings=restore_shardings,
-                                loader=restore_loader) \
-                if ckpt is not None else None
+            with prof.span("sentinel"):
+                res = ckpt.rollback(cur_state, shardings=restore_shardings,
+                                    loader=restore_loader) \
+                    if ckpt is not None else None
             if res is None:
                 raise FloatingPointError(
                     f"[sentinel] unrecoverable at step {at_step}: rollback "
@@ -852,6 +932,8 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
         stall_s = 0.0
         while True:
             while step < args.steps:
+                prof.begin_step(step, None if inflight is None
+                                else inflight[1])
                 if session.preempt:
                     break                          # graceful drain below
                 if step == args.fail_at_step:
@@ -938,20 +1020,24 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
                             "sigma-blowup": health_lib.INJECT_NONE}[kind]
                     eta_scale = (BLOWUP_ETA_SCALE
                                  if kind == "sigma-blowup" else 1.0)
-                    state, metrics = jit_step(state, batch, jnp.float32(lr),
-                                              jnp.int32(code),
-                                              do_subspace_update=do_update,
-                                              eta_scale=eta_scale)
+                    with prof.span("dispatch"):
+                        state, metrics = jit_step(
+                            state, batch, jnp.float32(lr), jnp.int32(code),
+                            do_subspace_update=do_update,
+                            eta_scale=eta_scale)
                 else:
-                    state, metrics = jit_step(state, batch, jnp.float32(lr),
-                                              do_subspace_update=do_update)
+                    with prof.span("dispatch"):
+                        state, metrics = jit_step(
+                            state, batch, jnp.float32(lr),
+                            do_subspace_update=do_update)
                 if stall_s:
                     # slow-host injection: a pure host-side stall — the
                     # step's wall time inflates (the straggler watchdog
                     # must flag it at drain) but device state is untouched
                     time.sleep(stall_s)
                     stall_s = 0.0
-                nbatch, nbatch_ok = fetch(step + 1)  # prefetch under compute
+                with prof.span("fetch"):             # prefetch under compute
+                    nbatch, nbatch_ok = fetch(step + 1)
                 act = HealthSentinel.OK
                 if inflight is not None:
                     act = drain(*inflight)
@@ -981,9 +1067,11 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
                         state, step = rb
                         batch, batch_ok = fetch(step)
                         continue
-                    ckpt.save(step, state, extra_meta=ckpt_extra,
-                              known_good=(act == HealthSentinel.OK))
+                    with prof.span("checkpoint"):
+                        ckpt.save(step, state, extra_meta=ckpt_extra,
+                                  known_good=(act == HealthSentinel.OK))
                 step += 1
+            prof.begin_step(None)
             if session.preempt:
                 break
             if inflight is None:
@@ -1030,9 +1118,10 @@ def _run(args, ctx, session: _FailoverSession) -> dict:
             print(f"[train] preemption drain complete at step {save_step}"
                   " — exiting cleanly for restart", flush=True)
         elif ckpt:
-            ckpt.save(args.steps - 1, state, blocking=True,
-                      extra_meta=ckpt_extra,
-                      known_good=(last_act == HealthSentinel.OK))
+            with prof.span("checkpoint"):
+                ckpt.save(args.steps - 1, state, blocking=True,
+                          extra_meta=ckpt_extra,
+                          known_good=(last_act == HealthSentinel.OK))
 
         wall = time.time() - session.t_start
         paths = [{"entry": e, "path": p, "shapes": [list(x) for x in shp],

@@ -257,13 +257,19 @@ def decoder_forward(params, tokens, cfg: ModelConfig, extras=None,
     :func:`repro.models.common.tapped_matmul` so their backward emits the
     SubTrack projection statistics as the seeds' cotangents.  ``None``
     (the default) leaves the forward/backward bit-exactly unchanged.
+
+    The named scopes ``embed``, ``decoder`` (the layer scan, with
+    ``attention`` and ``mlp`` inside each block) and ``head_loss`` name the
+    layers in the compiled step's ``op_name`` metadata and nowhere else; a
+    profile's device time is split by them (``benchmarks/chip/scopes.py``).
     """
     extras = extras or {}
     ctx = get_mesh_context()
     B, S = tokens.shape
     positions = jnp.arange(S)[None, :]
     seq_ax = ctx.model_axis if cfg.seq_shard_residual else None
-    x = _embed(params, tokens, cfg, extras)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, extras)
     x = shard(x, ctx.batch_axes, seq_ax, None)
     layer_taps = (taps or {}).get("layers", {})
 
@@ -271,17 +277,19 @@ def decoder_forward(params, tokens, cfg: ModelConfig, extras=None,
         x, aux = carry
         p, is_local, lt = layer
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        if cfg.attn_type == "mla":
-            a, _ = mla_block(h, p["attn"], cfg, positions, extras, ctx)
-        else:
-            a, _ = gqa_block(h, p["attn"], cfg, positions,
-                             _layer_window(cfg, is_local), extras, ctx,
-                             taps=lt.get("attn"))
+        with jax.named_scope("attention"):
+            if cfg.attn_type == "mla":
+                a, _ = mla_block(h, p["attn"], cfg, positions, extras, ctx)
+            else:
+                a, _ = gqa_block(h, p["attn"], cfg, positions,
+                                 _layer_window(cfg, is_local), extras, ctx,
+                                 taps=lt.get("attn"))
         if "ln1_post" in p:
             a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
         x = x + a
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        f, aux_l = mlp_block(h, p["mlp"], cfg, ctx, taps=lt.get("mlp"))
+        with jax.named_scope("mlp"):
+            f, aux_l = mlp_block(h, p["mlp"], cfg, ctx, taps=lt.get("mlp"))
         if "ln2_post" in p:
             f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
         x = x + f
@@ -296,18 +304,20 @@ def decoder_forward(params, tokens, cfg: ModelConfig, extras=None,
         x, aux = carry
         p, is_local, lt = layer
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        if cfg.attn_type == "mla":
-            a, _ = mla_block(h, p["attn"], cfg, positions, extras, ctx)
-        else:
-            a, _ = gqa_block(h, p["attn"], cfg, positions,
-                             _layer_window(cfg, is_local), extras, ctx,
-                             taps=lt.get("attn"))
+        with jax.named_scope("attention"):
+            if cfg.attn_type == "mla":
+                a, _ = mla_block(h, p["attn"], cfg, positions, extras, ctx)
+            else:
+                a, _ = gqa_block(h, p["attn"], cfg, positions,
+                                 _layer_window(cfg, is_local), extras, ctx,
+                                 taps=lt.get("attn"))
         if "ln1_post" in p:
             a = rms_norm(a, p["ln1_post"], cfg.norm_eps)
         a = jax.ad_checkpoint.checkpoint_name(a, "block_attn_out")
         x = x + a
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        f, aux_l = mlp_block(h, p["mlp"], cfg, ctx, taps=lt.get("mlp"))
+        with jax.named_scope("mlp"):
+            f, aux_l = mlp_block(h, p["mlp"], cfg, ctx, taps=lt.get("mlp"))
         if "ln2_post" in p:
             f = rms_norm(f, p["ln2_post"], cfg.norm_eps)
         f = jax.ad_checkpoint.checkpoint_name(f, "block_mlp_out")
@@ -327,11 +337,13 @@ def decoder_forward(params, tokens, cfg: ModelConfig, extras=None,
             policy=jax.checkpoint_policies.save_only_these_names(
                 "block_attn_out", "block_mlp_out"))
 
-    (x, aux), _ = jax.lax.scan(
-        block, (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], _local_flags(cfg), layer_taps))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return _logits(params, x, cfg, (taps or {}).get("lm_head")), aux
+    with jax.named_scope("decoder"):
+        (x, aux), _ = jax.lax.scan(
+            block, (x, jnp.zeros((), jnp.float32)),
+            (params["layers"], _local_flags(cfg), layer_taps))
+    with jax.named_scope("head_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return _logits(params, x, cfg, (taps or {}).get("lm_head")), aux
 
 
 def decoder_loss(params, batch, cfg: ModelConfig, remat: str = "full",
@@ -339,8 +351,9 @@ def decoder_loss(params, batch, cfg: ModelConfig, remat: str = "full",
     tokens = batch["tokens"]
     extras = {k: v for k, v in batch.items() if k != "tokens"}
     logits, aux = decoder_forward(params, tokens, cfg, extras, remat, taps)
-    labels, mask = shift_labels(tokens)
-    loss = cross_entropy(logits, labels, mask, cfg.vocab_size)
+    with jax.named_scope("head_loss"):
+        labels, mask = shift_labels(tokens)
+        loss = cross_entropy(logits, labels, mask, cfg.vocab_size)
     return loss + aux, {"ce_loss": loss, "aux_loss": aux}
 
 
