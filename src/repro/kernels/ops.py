@@ -36,7 +36,10 @@ reference, which keeps odd user models working.  In compiled mode every
 dispatch is recorded per (entry point, path, shapes) at trace time — path is
 ``kernel``, ``composite`` (several launches instead of the fused one) or
 ``reference`` — and the first trace of each fallback is printed, so a
-fallback on the chip is never silent (:func:`dispatch_tally`).
+fallback on the chip is never silent (:func:`dispatch_tally`).  Training
+attention's choice between the flash kernel and ``blocked_attention``
+(``repro.models.attention.self_attention``) is tallied here too, as entry
+``flash_attention``.
 
 Mesh-native execution: every entry point here is a PURE LOCAL launch.
 Inside ``shard_map`` the optimizer runs the same kernels on per-shard

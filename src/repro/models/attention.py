@@ -1,14 +1,27 @@
-"""Attention: memory-efficient blocked online-softmax (training/prefill),
-single-token decode against (possibly ring-buffered) KV caches, GQA and
-MLA variants, sliding-window and logit-softcap support.
+"""Attention: training/prefill self-attention, single-token decode against
+(possibly ring-buffered) KV caches, GQA and MLA variants, sliding-window and
+logit-softcap support.
 
-The blocked path is flash-attention-structured pure JAX: an outer
+Training runs :func:`self_attention`, which picks the path from its inputs:
+
+- the fused Pallas flash kernel, forward and backward
+  (``repro.kernels.flash_attention``), where the kernels run (compiled on
+  the chip, interpret mode in tests), the sequence is its own context
+  (``q_offset == 0``, T == S, S a multiple of 128) and the window and
+  soft-cap are static Python numbers; on a mesh it runs per shard under
+  ``shard_map``, since GSPMD cannot partition a Pallas kernel;
+- :func:`blocked_attention` otherwise: on the CPU, for a traced per-layer
+  window (gemma2's alternation), and for prefill continuations.  Every
+  choice on the chip is tallied in ``repro.kernels.ops.dispatch_tally``.
+
+:func:`blocked_attention` is flash-attention-structured pure JAX: an outer
 ``lax.map`` over query blocks and an inner ``lax.scan`` over KV blocks
 carrying (running-max, normalizer, accumulator).  Peak live logits are
-``(B, H, q_block, kv_block)`` instead of ``(B, H, S, S)`` — the difference
-between fitting and OOM at seq 32k.  A Pallas TPU kernel implementing the
-same schedule lives in repro/kernels/flash_attention.py; this module is the
-portable reference the kernel is validated against.
+``(B, H, q_block, kv_block)`` instead of ``(B, H, S, S)``, but under
+autodiff the scan stores every block pair's float32 probabilities in HBM,
+which is what the kernel avoids.  It is the portable path, the one the
+paged and chunked prefills, zamba and the encoder-decoder call, and the
+kernel's test oracle.
 """
 
 from __future__ import annotations
@@ -18,6 +31,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.distributed.context import get_mesh_context
+from repro.kernels import flash_attention as flash
+from repro.kernels import ops as kernel_ops
 
 Array = jax.Array
 
@@ -108,6 +126,51 @@ def blocked_attention(
     outs = jax.lax.map(one_q_block, (qb, jnp.arange(nq)))        # (nq,B,bq,Hkv,G,vd)
     out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, Hq, vd)
     return out.astype(q.dtype)
+
+
+def self_attention(
+    q: Array,                 # (B, S, Hq, hd)
+    k: Array,                 # (B, T, Hkv, hd)
+    v: Array,                 # (B, T, Hkv, vd)
+    *,
+    window=None,
+    softcap: float = 0.0,
+    q_offset: int = 0,
+    q_block: int = 512,
+    kv_block: int = 1024,
+) -> Array:
+    """Causal training/prefill attention with :func:`blocked_attention`'s
+    contract: the fused flash kernel where it takes the inputs, else
+    :func:`blocked_attention` (``q_block`` / ``kv_block`` are its blocks)."""
+    mode = kernel_ops._mode()
+    S, T = q.shape[1], k.shape[1]
+    fits = (mode != "ref" and isinstance(q_offset, int) and q_offset == 0
+            and T == S and S % flash.LANES == 0
+            and (window is None or isinstance(window, int))
+            and isinstance(softcap, (int, float)))
+    kernel_ops._route("flash_attention",
+                      kernel_ops.KERNEL if fits else kernel_ops.REFERENCE,
+                      q, k, v)
+    if not fits:
+        return blocked_attention(q, k, v, causal=True, window=window,
+                                 softcap=softcap, q_offset=q_offset,
+                                 q_block=q_block, kv_block=kv_block)
+
+    def attend(q, k, v):
+        return flash.flash_attention(q, k, v, window=window, softcap=softcap,
+                                     interpret=(mode == "interpret"))
+
+    ctx = get_mesh_context()
+    if ctx.mesh.size == 1:
+        return attend(q, k, v)
+    # GSPMD cannot partition a Pallas kernel: run it on each device's shard,
+    # batch over the data axes and heads over the model axis where they
+    # divide (both head counts, so each shard keeps whole KV groups)
+    heads = q.shape[2] % ctx.tp == 0 and k.shape[2] % ctx.tp == 0
+    spec = P(ctx.batch_axes if q.shape[0] % ctx.dp == 0 else None, None,
+             ctx.model_axis if heads else None, None)
+    return jax.shard_map(attend, mesh=ctx.mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -266,7 +329,8 @@ def init_mla_cache(n_layers: int, batch: int, max_len: int, kv_rank: int,
 def mla_prefill_attention(q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, *,
                           softcap: float = 0.0, q_block: int = 512,
                           kv_block: int = 1024) -> Array:
-    """Prefill MLA: expand the latent into per-head K/V and run blocked attn.
+    """Prefill MLA: expand the latent into per-head K/V and attend
+    (:func:`self_attention`).
 
     q_nope: (B,S,H,dn)  q_rope: (B,S,H,dr)  c_kv: (B,T,kvr)  k_rope: (B,T,dr)
     w_uk: (kvr, H, dn)  w_uv: (kvr, H, dv)
@@ -279,8 +343,8 @@ def mla_prefill_attention(q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, *,
                                 (B, T, H, k_rope.shape[-1]))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    return blocked_attention(q, k, val, causal=True, softcap=softcap,
-                             q_block=q_block, kv_block=kv_block)
+    return self_attention(q, k, val, softcap=softcap, q_block=q_block,
+                          kv_block=kv_block)
 
 
 def mla_decode_attention(q_nope, q_rope, c_cache, kr_cache, w_uk, w_uv,

@@ -175,8 +175,8 @@ def gqa_block(x, p, cfg: ModelConfig, positions, window, extras,
     q, k = _rope_q_k(cfg, q, k, positions, extras)
     q = shard(q, ctx.batch_axes, None, ctx.model_axis, None)
     k = shard(k, ctx.batch_axes, None, ctx.model_axis, None)
-    out = attn.blocked_attention(
-        q, k, v, causal=True, window=window, softcap=cfg.attn_softcap,
+    out = attn.self_attention(
+        q, k, v, window=window, softcap=cfg.attn_softcap,
         q_block=cfg.q_block, kv_block=cfg.kv_block)
     out = out.reshape(B, S, cfg.n_heads * hd)
     return maybe_tapped_matmul(out, p["wo"], taps.get("wo")), (k, v)

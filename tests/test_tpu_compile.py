@@ -2,7 +2,8 @@
 at the widths the chip runs them: the 1B ladder model's attention, MLP
 (d_ff = 5461, a ragged column extent) and head leaves at rank 512, the
 grad-fused tap at batch 8 x seq 256 tokens, the row-regime tracking panel
-of a four-chip mesh, and qwen1.5-4b's paged decode.  The TPU compiler
+of a four-chip mesh, qwen1.5-4b's paged decode, and the training cells'
+flash attention, forward and backward.  The TPU compiler
 refuses here what interpret mode cannot show: misaligned blocks, scoped
 VMEM overruns, compiler faults.  Nothing runs; no result is checked.
 
@@ -15,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import grassmann, paged_attention
+from repro.kernels import flash_attention, grassmann, paged_attention
 
 R = 512                                  # llama-1b's paper rank
 LEAVES = [(2048, 2048), (2048, 5461), (2048, 32000)]   # attn, MLP, head
@@ -23,16 +24,20 @@ TOKENS = 8 * 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -117,3 +122,56 @@ def test_paged_attention_qwen_decode(compile_for):
     compile_for(paged_attention.paged_attention, ((B, H, hd), BF16),
                 ((nb, bs, H, hd), BF16), ((nb, bs, H, hd), BF16),
                 ((B, W), I32), ((B,), I32))
+
+
+@pytest.mark.parametrize("H,hd,vd", [(20, 128, 128),     # qwen1.5-4b
+                                     (40, 96, 64)])      # minicpm3-4b MLA
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_flash_attention_train_cells(compile_for, H, hd, vd, pass_):
+    """Under ``highest`` default matmul precision, as the benchmark's warm
+    start runs the model: the kernels' bf16 products must not ask Mosaic
+    for a float32 contraction."""
+    S = 2048
+    shapes = [((1, S, H, d), BF16) for d in (hd, hd, vd)]
+
+    def grads(q, k, v, do):
+        return jax.vjp(flash_attention.flash_attention, q, k, v)[1](do)
+
+    with jax.default_matmul_precision("highest"):
+        if pass_ == "forward":
+            text = compile_for(flash_attention.flash_attention, *shapes)
+        else:
+            text = compile_for(grads, *shapes, ((1, S, H, vd), BF16))
+    names = ("flash_fwd",) if pass_ == "forward" else ("flash_dq", "flash_dkv")
+    assert all(name in text for name in names)
+
+
+@pytest.mark.parametrize("H,Hkv", [(20, 20), (20, 2)])
+def test_flash_attention_on_a_four_chip_mesh(compile_for, topo, monkeypatch,
+                                             H, Hkv):
+    """On a mesh the training attention runs the kernel per shard (GSPMD
+    cannot partition it): heads over the model axis where both head
+    counts divide it, else replicated."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed import context
+    from repro.kernels import ops
+    from repro.models.attention import self_attention
+
+    monkeypatch.setattr(ops, "_mode", lambda: "compiled")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    ctx = context.MeshContext(mesh=mesh)
+    monkeypatch.setattr(context, "_CURRENT", ctx)
+
+    def arg(h, d=128):
+        spec = P(None, None, "model" if h % 4 == 0 else None, None)
+        return jax.ShapeDtypeStruct((1, 2048, h, d), BF16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def grads(q, k, v, do):
+        return jax.vjp(self_attention, q, k, v)[1](do)
+
+    text = jax.jit(grads).lower(arg(H), arg(Hkv), arg(Hkv), arg(H)
+                                ).compile().as_text()
+    assert "flash_dq" in text and "flash_dkv" in text
