@@ -2,7 +2,8 @@
 
 A cell is one entry of ``BENCHMARK.json``'s ``workloads``.  Its traffic
 parameters are ``workloads/<cell>.json``, its model configuration
-``configs/<config>.json``, its timed loop ``kinds/<kind>.py`` and each of
+``configs/<config>.json`` (whose ``reference`` names its model family,
+``refs/<reference>.py``), its timed loop ``kinds/<kind>.py`` and each of
 its per-layer metrics ``metrics/<metric>.py``.  Nothing here names a cell,
 a configuration or a metric: a later cell is added by adding files.
 """

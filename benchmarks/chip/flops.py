@@ -5,48 +5,36 @@ parameter per token forward and 6 forward + backward, the head counted
 and the embedding lookup not; plus causal attention, where a token at
 position p attends p + 1 keys and QK^T and PV cost 2 x (qk + v) FLOPs per
 key per layer (times 3 with the backward); recomputation not counted.
+The sizes come from the configuration's family (``model.family``).
 """
 
 from __future__ import annotations
 
-from benchmarks.chip.model import attention_kind, head_dim
+from pathlib import Path
+
+from benchmarks.chip.bench import BENCH_DIR
+from benchmarks.chip.model import family
 
 
-def matmul_params(conf: dict) -> int:
-    """Weights that multiply activations, per the published sizes: every
-    layer's attention and MLP projections and the head over the published
-    vocabulary (the program's padding of the vocabulary is not model
-    work)."""
-    d, f = conf["hidden_size"], conf["intermediate_size"]
-    L, H = conf["num_hidden_layers"], conf["num_attention_heads"]
-    if attention_kind(conf) == "mla":
-        qr, kvr = conf["q_lora_rank"], conf["kv_lora_rank"]
-        dn, dr = conf["qk_nope_head_dim"], conf["qk_rope_head_dim"]
-        dv = conf["v_head_dim"]
-        attn = (d * qr + qr * H * (dn + dr) + d * kvr + d * dr
-                + kvr * H * dn + kvr * H * dv + H * dv * d)
-    else:
-        hd, Hkv = head_dim(conf), conf["num_key_value_heads"]
-        attn = 2 * d * H * hd + 2 * d * Hkv * hd
-    return L * (attn + 3 * d * f) + d * conf["vocab_size"]
+def matmul_params(conf: dict, bench_dir: Path = BENCH_DIR) -> int:
+    """Weights that multiply one token's activations, per the published
+    sizes, the head included."""
+    return family(conf, bench_dir).matmul_params(conf)
 
 
-def attn_dims(conf: dict) -> tuple[int, int, int]:
-    """(layers, heads * qk dim, heads * v dim)."""
-    H = conf["num_attention_heads"]
-    if attention_kind(conf) == "mla":
-        qk = conf["qk_nope_head_dim"] + conf["qk_rope_head_dim"]
-        return conf["num_hidden_layers"], H * qk, H * conf["v_head_dim"]
-    hd = head_dim(conf)
-    return conf["num_hidden_layers"], H * hd, H * hd
+def attn_dims(conf: dict, bench_dir: Path = BENCH_DIR) -> tuple[int, int,
+                                                                int]:
+    """(attention layers, heads * qk dim, heads * v dim)."""
+    return family(conf, bench_dir).attn_dims(conf)
 
 
-def train_flops_per_token(conf: dict, seq_len: int) -> float:
+def train_flops_per_token(conf: dict, seq_len: int,
+                          bench_dir: Path = BENCH_DIR) -> float:
     """Forward + backward model FLOPs per trained token: 6 per matmul
     parameter plus causal attention (a token at position p attends p + 1
     keys: QK^T and PV are 2 * (qk + v) FLOPs per key, times 3 for the
     backward)."""
-    L, qk, v = attn_dims(conf)
+    L, qk, v = attn_dims(conf, bench_dir)
     mean_keys = (seq_len + 1) / 2.0
-    return 6.0 * matmul_params(conf) + 3.0 * 2.0 * L * (qk + v) * mean_keys
-
+    return 6.0 * matmul_params(conf, bench_dir) + 3.0 * 2.0 * L * (
+        qk + v) * mean_keys

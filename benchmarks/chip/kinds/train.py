@@ -17,13 +17,14 @@ from __future__ import annotations
 import gc
 import math
 import time
+from functools import partial
 
 import numpy as np
 
 from benchmarks.chip import flops as flops_lib
 from benchmarks.chip import model as model_lib
-from benchmarks.chip.bench import BenchError
-from benchmarks.chip.refs import decoder as ref
+from benchmarks.chip.bench import BENCH_DIR, BenchError
+from benchmarks.chip.refs import subtrack
 
 # The warm start's range finder is float32 arithmetic; on a TPU a jnp
 # matmul of float32 operands runs one bfloat16 pass unless asked for more,
@@ -33,40 +34,38 @@ from benchmarks.chip.refs import decoder as ref
 WARM_START_PRECISION = "highest"
 
 
-def program_config(conf):
-    """The program's ModelConfig for a configuration file."""
+def program_config(conf, bench_dir=BENCH_DIR):
+    """The program's ModelConfig for a configuration file: the file's
+    depth, RoPE base and norm epsilon, and every size that the family's
+    layout depends on checked against the program's."""
     from repro.configs.registry import get_config
 
     prog = conf["program"]
     cfg = get_config(prog["arch"], smoke=prog.get("smoke", False)).with_(
         n_layers=conf["num_hidden_layers"], rope_theta=conf["rope_theta"],
         norm_eps=conf["rms_norm_eps"])
-    want = {"d_model": conf["hidden_size"], "d_ff": conf["intermediate_size"],
-            "n_heads": conf["num_attention_heads"],
-            "n_kv_heads": conf["num_key_value_heads"],
-            "vocab_size": conf["vocab_size"],
-            "tie_embeddings": conf.get("tie_word_embeddings", False),
-            "attn_type": model_lib.attention_kind(conf)}
-    got = {k: getattr(cfg, k) for k in want}
-    if cfg.mla is not None:
-        for k in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                  "qk_rope_head_dim", "v_head_dim"):
-            want[k], got[k] = conf.get(k), getattr(cfg.mla, k)
+    pairs = {"d_model": (conf["hidden_size"], cfg.d_model),
+             "vocab_size": (conf["vocab_size"], cfg.vocab_size),
+             "tie_embeddings": (conf.get("tie_word_embeddings", False),
+                                cfg.tie_embeddings),
+             **model_lib.family(conf, bench_dir).program_keys(conf, cfg)}
+    want = {k: w for k, (w, _) in pairs.items()}
+    got = {k: g for k, (_, g) in pairs.items()}
     if got != want:
         raise BenchError(f"program config {prog['arch']} differs from the "
                          f"file: {got} != {want}")
     return cfg
 
 
-def _slice_norms(flat: dict) -> dict:
-    """{path: array} -> {path: float32 norms}, one per layer for stacked
-    layer leaves (paths that start with "layers")."""
+def _slice_norms(flat: dict, stack_axes) -> dict:
+    """{path: array} -> {path: float32 norms}, one per slice: the array
+    indexed by its ``stack_axes(path)`` leading axes."""
     import jax.numpy as jnp
 
     out = {}
     for path, x in flat.items():
         x = x.astype(jnp.float32)
-        axes = tuple(range(1 if path[0] == "layers" else 0, x.ndim))
+        axes = tuple(range(stack_axes(path), x.ndim))
         out[path] = jnp.sqrt(jnp.sum(x * x, axis=axes))
     return out
 
@@ -76,11 +75,8 @@ def _named(norms: dict) -> dict:
     out = {}
     for path, v in norms.items():
         v = np.asarray(v)
-        if path[0] == "layers":
-            for i, x in enumerate(v):
-                out["layers." + ".".join(path[1:]) + f".{i}"] = float(x)
-        else:
-            out[".".join(path)] = float(v)
+        for idx in np.ndindex(v.shape):
+            out[subtrack.slice_name(path, idx)] = float(v[idx])
     return out
 
 
@@ -145,7 +141,7 @@ NUMBERS = ("loss_gap", "gnorm_gap", "dense_grad_gap", "grad_gap",
 class Program:
     """The system under test, built for one training cell."""
 
-    def __init__(self, conf, wl):
+    def __init__(self, conf, wl, bench_dir=BENCH_DIR):
         import jax
 
         from repro.core.api import get_optimizer
@@ -153,12 +149,16 @@ class Program:
         from repro.launch.steps import make_train_step, make_warm_start
         from repro.models.api import build_model
 
-        self.conf, self.wl = conf, wl
+        self.conf, self.wl, self.bench_dir = conf, wl, bench_dir
         o = wl["optimizer"]
-        self.cfg = program_config(conf)
+        self.cfg = program_config(conf, bench_dir)
+        self.norms = jax.jit(partial(
+            _slice_norms,
+            stack_axes=model_lib.family(conf, bench_dir).stack_axes))
         self.bundle = build_model(self.cfg)
         model_lib.check_layout(
-            jax.eval_shape(self.bundle.init, jax.random.PRNGKey(0)), conf)
+            jax.eval_shape(self.bundle.init, jax.random.PRNGKey(0)), conf,
+            bench_dir)
         _check_init(o["init"])
         self.optimizer = get_optimizer(
             "subtrack", rank=o["rank"], update_interval=o["update_interval"],
@@ -202,7 +202,7 @@ class Program:
 
         from repro.launch.steps import TrainState
 
-        params = model_lib.make_params(self.conf, seed)
+        params = model_lib.make_params(self.conf, seed, self.bench_dir)
         paths = [p for p, _ in model_lib.leaves(params)]
         state = TrainState(params=params, opt=self.init_opt(params))
         with jax.default_matmul_precision(precision):
@@ -221,7 +221,7 @@ class Program:
                 gnorm = metrics["grad_norm"]
                 moments = {p: _first_moment(state.opt.inner, p)
                            for p in paths}
-                norms = jax.device_get(jax.jit(_slice_norms)(moments))
+                norms = jax.device_get(self.norms(moments))
                 first_grad = {k: v / (1.0 - b1)
                               for k, v in _named(norms).items()}
         readings = {"losses": [float(x) for x in losses],
@@ -256,13 +256,16 @@ def _first_moment(inner, path):
     return node.M
 
 
-def change_norms(conf, seed, host_params, paths) -> dict:
+def change_norms(conf, seed, host_params, paths,
+                 bench_dir=BENCH_DIR) -> dict:
     """Per-slice ||P_3 - P_0|| of the program, P_0 drawn again from the
     seed (after the program's state is freed)."""
     import jax
     import jax.numpy as jnp
 
-    p0 = model_lib.make_params(conf, seed)
+    p0 = model_lib.make_params(conf, seed, bench_dir)
+    norms = jax.jit(partial(
+        _slice_norms, stack_axes=model_lib.family(conf, bench_dir).stack_axes))
     out = {}
     diff = jax.jit(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32))
     for path in paths:
@@ -270,16 +273,21 @@ def change_norms(conf, seed, host_params, paths) -> dict:
         for k in path:
             a, b = a[k], b[k]
         d = {path: diff(jnp.asarray(a), b)}
-        out.update(_named(jax.device_get(jax.jit(_slice_norms)(d))))
+        out.update(_named(jax.device_get(norms(d))))
     del p0
     return out
 
 
-def reference_readings(conf, wl, seed, batch, fmt=None, rows=None) -> dict:
-    o = dict(wl["optimizer"])
+def reference_readings(conf, wl, seed, batch, fmt=None, rows=None,
+                       bench_dir=BENCH_DIR) -> dict:
+    """The plain reference's readings: the family's float32 model (or its
+    ``fmt`` control) trained by ``refs/subtrack.py`` from the seed's
+    weights on batches 0..3."""
+    fam = model_lib.family(conf, bench_dir)
     batches = [batch(i)["tokens"] for i in range(4)]
-    return ref.train(model_lib.make_params(conf, seed), batches, conf, o,
-                     fmt=fmt, rows=rows)
+    return subtrack.train(fam.Model(conf, fmt), fam.stack_axes,
+                          model_lib.make_params(conf, seed, bench_dir),
+                          batches, dict(wl["optimizer"]), rows=rows)
 
 
 def run(r) -> None:
@@ -287,9 +295,9 @@ def run(r) -> None:
 
     from repro.kernels import ops
 
-    conf, wl = r.cell.config, r.cell.workload
+    conf, wl, bench_dir = r.cell.config, r.cell.workload, r.cell.bench_dir
     seed = model_lib.seed32(r.seed)
-    prog = Program(conf, wl)
+    prog = Program(conf, wl, bench_dir)
     batch = prog.data(seed)
     ops.reset_dispatch_tally()
     state, lr, readings = prog.start(seed, batch, sample=r.sample_memory)
@@ -343,15 +351,17 @@ def run(r) -> None:
     r.metric("peak_hbm_gib", r.hbm_footprint_bytes() / 2**30, "GiB")
     r.host.update(kind="train", steps=steps, window_s=seconds,
                   tokens_per_s=tps,
-                  flops_per_token=flops_lib.train_flops_per_token(conf, S),
-                  lowrank=lowrank_shapes(conf, wl["optimizer"]["rank"]))
+                  flops_per_token=flops_lib.train_flops_per_token(
+                      conf, S, bench_dir),
+                  lowrank=lowrank_shapes(conf, wl["optimizer"]["rank"],
+                                         bench_dir))
     r.log(f"window: {steps} steps of {B}x{S} tokens in {seconds:.3f}s")
 
     del state, inflight, metrics, nxt
     gc.collect()
     readings["change"] = change_norms(conf, seed, readings.pop("params"),
-                                      readings.pop("paths"))
-    refr = reference_readings(conf, wl, seed, batch)
+                                      readings.pop("paths"), bench_dir)
+    refr = reference_readings(conf, wl, seed, batch, bench_dir=bench_dir)
     c = compare(readings, refr)
     lim = wl["limits"]
     r.log("readings not compared: " + ", ".join(
@@ -365,12 +375,14 @@ def run(r) -> None:
         r.check(name, c[name], limit)
 
 
-def lowrank_shapes(conf, rank) -> list[tuple[int, int, int]]:
-    """(m, n, r) of every low-rank matrix a plain step updates."""
+def lowrank_shapes(conf, rank,
+                   bench_dir=BENCH_DIR) -> list[tuple[int, int, int]]:
+    """(m, n, r) of every low-rank slice a plain step updates."""
+    fam = model_lib.family(conf, bench_dir)
     out = []
-    for path, shape in model_lib.leaves(model_lib.param_shapes(conf)):
-        stack = shape[0] if path[0] == "layers" else 1
-        mat = shape[1:] if path[0] == "layers" else shape
-        if len(mat) == 2 and min(mat) > rank:
-            out += [(min(mat), max(mat), rank)] * stack
+    for path, shape in model_lib.leaves(fam.param_shapes(conf)):
+        n = fam.stack_axes(path)
+        if subtrack.is_lowrank(shape[n:], rank):
+            out += [(min(shape[n:]), max(shape[n:]), rank)] * math.prod(
+                shape[:n])
     return out

@@ -6,4 +6,4 @@ from benchmarks.chip import scopes
 
 
 def read(rec):
-    return scopes.layer_ms(rec, scopes.SCOPES + (None,), ("recompute",))
+    return scopes.layer_ms(rec, passes=("recompute",))

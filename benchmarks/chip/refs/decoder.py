@@ -1,11 +1,28 @@
-"""Plain float32 reference of the decoders the configurations describe, and
-of SubTrack++'s warm start and plain step.
+"""The dense decoder family: GQA and MLA decoders with a SwiGLU MLP, as the
+configurations name it with ``"reference": "decoder"``.
 
-Straightforward ``jax.numpy`` at matmul precision ``highest``: one
-sequence at a time, full causal softmax, no kernels, cache or fusion.  It
-imports nothing of the system under test; it reads the weights that
-``model.make_params`` draws from the seed, in the layout documented there
-(RMSNorm gains are stored as offsets from 1).
+A family module is what the harness knows of a model's shape.  It gives:
+
+* ``param_shapes(conf)``: the program's parameter layout (stacked layers,
+  ``(in, out)`` matrices, RMSNorm scales stored as offsets from 1), written
+  out from the configuration's published sizes and checked against the
+  program's own shapes before a run;
+* ``stack_axes(path)``: a leaf's count of leading stack axes, by which the
+  optimizer's slices are cut and named;
+* ``init_scale(path, shape)``: the standard deviation of a seeded weight;
+* ``program_keys(conf, cfg)``: the program's config fields this layout
+  depends on, as (from the file, the program's) pairs;
+* ``matmul_params(conf)`` and ``attn_dims(conf)``: the sizes model FLOPs
+  are counted from (``flops.py``);
+* ``Model``: the plain float32 forward and backward, split into pieces, that
+  ``refs/subtrack.py`` trains;
+* optionally ``EXTRA_SCOPES``: the program's scope names for this family's
+  own layers, beside ``scopes.SCOPES``.
+
+The reference is straightforward ``jax.numpy`` at matmul precision
+``highest``: one sequence at a time, full causal softmax, no kernels,
+cache or fusion.  It imports nothing of the system under test; it reads
+the weights that ``model.make_params`` draws from the seed.
 
 Blocks (the configuration file lists every departure from the published
 model under ``assumed``):
@@ -20,20 +37,6 @@ model under ``assumed``):
 ``fmt="fp8"`` rounds both operands of every matrix product, forward and
 backward, to float8 e4m3 with a per-tensor scale before multiplying in
 float32: the lower-precision control that the comparison must reject.
-
-The optimizer part follows SubTrack++ (arXiv:2502.01586, Alg. 1) for the
-steps before the first subspace update.  S_0 spans the top-r left
-singular vectors of the first gradient (``init`` "svd"), or the randomized
-range finder's approximation of them (``init`` "randomized").  Each plain
-step projects the clipped gradient, runs Adam on the projection,
-back-projects, and adds the recovery term (Fira's column scaling of the
-orthogonal residual with the Eq. 12 growth limiter).  Matrices whose
-smaller side is at most r take plain Adam.  Parameters are stored in the
-configuration's bfloat16: each step computes its update in float32 and
-rounds the new parameter to bfloat16, as bf16-weight, fp32-state training
-does; the optimizer state is float32.  The backward runs layer by layer
-and twice per step (once for the global norm, once to apply), so that
-only one layer's gradient is held at a time.
 """
 
 from __future__ import annotations
@@ -46,7 +49,128 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
+TOP = ((), ())            # the pieces' key of the leaves outside the stack
+LAYERS = ("layers",)
 
+
+# ---------------------------------------------------------------------------
+# Layout, seeded weights and sizes
+# ---------------------------------------------------------------------------
+
+
+def padded_vocab(conf: dict) -> int:
+    step = int(conf["program"].get("vocab_round", 256))
+    return -(-conf["vocab_size"] // step) * step
+
+
+def attention_kind(conf: dict) -> str:
+    return "mla" if "kv_lora_rank" in conf else "gqa"
+
+
+def head_dim(conf: dict) -> int:
+    return conf.get("head_dim") or conf["hidden_size"] // conf[
+        "num_attention_heads"]
+
+
+def _map(fn, tree):
+    if isinstance(tree, dict):
+        return {k: _map(fn, v) for k, v in tree.items()}
+    return fn(tree)
+
+
+def param_shapes(conf: dict) -> dict:
+    """Nested dict of shapes in the program's layout."""
+    d, f = conf["hidden_size"], conf["intermediate_size"]
+    L, H = conf["num_hidden_layers"], conf["num_attention_heads"]
+    V = padded_vocab(conf)
+    if attention_kind(conf) == "mla":
+        qr, kvr = conf["q_lora_rank"], conf["kv_lora_rank"]
+        dn, dr = conf["qk_nope_head_dim"], conf["qk_rope_head_dim"]
+        dv = conf["v_head_dim"]
+        attn = {"w_dq": (d, qr), "q_norm": (qr,), "w_uq": (qr, H * (dn + dr)),
+                "w_dkv": (d, kvr), "kv_norm": (kvr,), "w_kr": (d, dr),
+                "w_uk": (kvr, H, dn), "w_uv": (kvr, H, dv),
+                "wo": (H * dv, d)}
+    else:
+        hd, Hkv = head_dim(conf), conf["num_key_value_heads"]
+        attn = {"wq": (d, H * hd), "wk": (d, Hkv * hd), "wv": (d, Hkv * hd),
+                "wo": (H * hd, d)}
+        if conf["program"].get("qkv_bias"):
+            attn.update(bq=(H * hd,), bk=(Hkv * hd,), bv=(Hkv * hd,))
+    layer = {"ln1": (d,), "ln2": (d,), "attn": attn,
+             "mlp": {"w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)}}
+    stack = lambda s: (L,) + s  # noqa: E731
+    shapes = {"embed": (V, d),
+              "layers": _map(stack, layer),
+              "final_norm": (d,)}
+    if not conf.get("tie_word_embeddings", False):
+        shapes["lm_head"] = (d, V)
+    return shapes
+
+
+def stack_axes(path: tuple) -> int:
+    """The layer stack's leaves have one leading stack axis."""
+    return 1 if path[0] == LAYERS[0] else 0
+
+
+def init_scale(path: tuple, shape: tuple) -> float:
+    name = path[-1]
+    if name in ("ln1", "ln2", "final_norm", "q_norm", "kv_norm"):
+        return 0.05            # offsets of the RMSNorm gains from 1
+    if name in ("bq", "bk", "bv"):
+        return 0.1
+    if name == "embed":
+        return 0.02
+    fan_in = shape[-3] if name in ("w_uk", "w_uv") else shape[-2]
+    return 1.0 / math.sqrt(fan_in)
+
+
+def program_keys(conf: dict, cfg) -> dict:
+    """{field of the program's ModelConfig: (the file's value, the
+    program's)} for the sizes this layout depends on."""
+    out = {"d_ff": (conf["intermediate_size"], cfg.d_ff),
+           "n_heads": (conf["num_attention_heads"], cfg.n_heads),
+           "n_kv_heads": (conf["num_key_value_heads"], cfg.n_kv_heads),
+           "attn_type": (attention_kind(conf), cfg.attn_type)}
+    if cfg.mla is not None:
+        for k in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                  "qk_rope_head_dim", "v_head_dim"):
+            out[k] = (conf.get(k), getattr(cfg.mla, k))
+    return out
+
+
+def matmul_params(conf: dict) -> int:
+    """Weights that multiply activations, per the published sizes: every
+    layer's attention and MLP projections and the head over the published
+    vocabulary (the program's padding of the vocabulary is not model
+    work)."""
+    d, f = conf["hidden_size"], conf["intermediate_size"]
+    L, H = conf["num_hidden_layers"], conf["num_attention_heads"]
+    if attention_kind(conf) == "mla":
+        qr, kvr = conf["q_lora_rank"], conf["kv_lora_rank"]
+        dn, dr = conf["qk_nope_head_dim"], conf["qk_rope_head_dim"]
+        dv = conf["v_head_dim"]
+        attn = (d * qr + qr * H * (dn + dr) + d * kvr + d * dr
+                + kvr * H * dn + kvr * H * dv + H * dv * d)
+    else:
+        hd, Hkv = head_dim(conf), conf["num_key_value_heads"]
+        attn = 2 * d * H * hd + 2 * d * Hkv * hd
+    return L * (attn + 3 * d * f) + d * conf["vocab_size"]
+
+
+def attn_dims(conf: dict) -> tuple[int, int, int]:
+    """(layers, heads * qk dim, heads * v dim)."""
+    H = conf["num_attention_heads"]
+    if attention_kind(conf) == "mla":
+        qk = conf["qk_nope_head_dim"] + conf["qk_rope_head_dim"]
+        return conf["num_hidden_layers"], H * qk, H * conf["v_head_dim"]
+    hd = head_dim(conf)
+    return conf["num_hidden_layers"], H * hd, H * hd
+
+
+# ---------------------------------------------------------------------------
+# The model
+# ---------------------------------------------------------------------------
 
 def quantize(x, fmt):
     if fmt is None:
@@ -185,20 +309,6 @@ def head_grads(w, x, labels, weight, conf, fmt, rows):
     return total, gw, gx.reshape(x.shape)
 
 
-# ---------------------------------------------------------------------------
-# Weights: the program's stacked layout -> per-layer pieces
-# ---------------------------------------------------------------------------
-
-
-def split_layers(params) -> tuple[dict, list[dict]]:
-    """(non-layer leaves, [layer l's leaves]), in their stored dtype."""
-    top = {k: v for k, v in params.items() if k != "layers"}
-    n = jax.tree.leaves(params["layers"])[0].shape[0]
-    layers = [jax.tree.map(lambda a, i=i: a[i], params["layers"])
-              for i in range(n)]
-    return top, layers
-
-
 def _f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
@@ -211,7 +321,12 @@ def _embed_grad(dx, tokens, rows):
 
 
 class Model:
-    """Jitted per-layer pieces of the reference, built once per config."""
+    """Jitted per-layer pieces of the reference, built once per config.
+
+    The weights are split into pieces keyed (prefix, index): the leaves
+    outside the layer stack under ``TOP``, layer l's under
+    ``(LAYERS, (l,))``; a leaf's path is the prefix and its path in the
+    piece, and the index says which slice of its stack axes it is."""
 
     def __init__(self, conf, fmt=None, head_rows=512):
         self.conf, self.fmt, self.head_rows = conf, fmt, head_rows
@@ -227,6 +342,15 @@ class Model:
         self.head = jax.jit(partial(head_grads, conf=conf, fmt=fmt),
                             static_argnames=("rows",))
 
+    def split(self, params) -> dict:
+        """The program's stacked layout -> {key: piece's leaves}, in their
+        stored dtype."""
+        pieces = {TOP: {k: v for k, v in params.items() if k != LAYERS[0]}}
+        for i in range(self.conf["num_hidden_layers"]):
+            pieces[(LAYERS, (i,))] = jax.tree.map(lambda a, i=i: a[i],
+                                                  params[LAYERS[0]])
+        return pieces
+
     def embed(self, top, tokens):
         return top["embed"][tokens].astype(jnp.float32)
 
@@ -237,11 +361,16 @@ class Model:
             xs.append(self.fwd(w, xs[-1]))
         return xs
 
-    def grads(self, top, layers, tokens, rows=None):
-        """Yields ("head", g) ... ("layer", l, g) ... ("embed", g) and
-        finally ("loss", mean).  ``rows`` (S,) bool keeps only those
-        positions in the mean (a fault: part of the batch left out)."""
+    def grads(self, pieces, tokens, rows=None):
+        """Yields (key, g), g the gradient of some of that piece's leaves,
+        nested as they are: the head's, then each layer's from the last,
+        then the embedding's; finally (None, mean loss).  ``rows`` (S,)
+        bool keeps only those positions in the mean (a fault: part of the
+        batch left out)."""
         B, S = tokens.shape
+        top = pieces[TOP]
+        layers = [pieces[(LAYERS, (i,))]
+                  for i in range(self.conf["num_hidden_layers"])]
         xs = self.forward(top, layers, tokens)
         labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
         keep = np.arange(S) < S - 1
@@ -258,215 +387,13 @@ class Model:
         total = float(total)
         dx = gx.reshape(xs[-1].shape)
         del gx
-        yield ("head", g_head)
+        yield TOP, g_head
         del g_head                       # one piece held at a time
         for i in range(len(layers) - 1, -1, -1):
             gw, dx = self.bwd(layers[i], xs[i], dx)
             xs[i + 1] = None
-            yield ("layer", i, gw)
+            yield (LAYERS, (i,)), gw
             del gw
-        yield ("embed", _embed_grad(dx, tokens, rows=top["embed"].shape[0]))
-        yield ("loss", total)
-
-
-# ---------------------------------------------------------------------------
-# SubTrack++ before its first subspace update
-# ---------------------------------------------------------------------------
-
-
-def is_lowrank(shape, rank) -> bool:
-    return len(shape) == 2 and min(shape) > rank
-
-
-def _canon(g):
-    return g.T if g.shape[0] > g.shape[1] else g
-
-
-def top_subspace(G, rank):
-    """Top-r left singular vectors of G (m <= n): eigenvectors of G G^T."""
-    w, U = jnp.linalg.eigh(jnp.matmul(G, G.T, precision=HIGHEST))
-    return U[:, ::-1][:, :rank]
-
-
-def range_finder(G, rank, seed, oversample, power_iters):
-    """Halko, Martinsson and Tropp's randomized range finder: the first r
-    columns of orth((G G^T)^q G Omega), Omega (n, r + p) standard normal
-    from ``jax.random.PRNGKey(seed)``."""
-    m, n = G.shape
-    k = min(rank + oversample, m)
-    omega = jax.random.normal(jax.random.PRNGKey(seed), (n, k), jnp.float32)
-    Y = jnp.matmul(G, omega, precision=HIGHEST)
-    for _ in range(power_iters):
-        Y = jnp.matmul(G, jnp.matmul(G.T, Y, precision=HIGHEST),
-                       precision=HIGHEST)
-    Q, _ = jnp.linalg.qr(Y)
-    return Q[:, :rank]
-
-
-@partial(jax.jit, static_argnames=("rank", "init"))
-def _warm(g, rank, init):
-    """S_0 from the first gradient: ``init`` is ("svd",) or
-    ("randomized", seed, oversample, power_iters)."""
-    G = _canon(g)
-    if init[0] == "svd":
-        return top_subspace(G, rank)
-    return range_finder(G, rank, *init[1:])
-
-
-@partial(jax.jit, static_argnames=("hp",), donate_argnums=(0,))
-def _lowrank_step(g, cscale, p, S, M, V, lam_prev, t, lr, hp):
-    b1, b2, eps, scale, zeta = hp
-    G = _canon(g) * cscale
-    Gt = jnp.matmul(S.T, G, precision=HIGHEST)
-    M = b1 * M + (1 - b1) * Gt
-    V = b2 * V + (1 - b2) * Gt * Gt
-    m_hat = M / (1 - b1 ** t)
-    v_hat = V / (1 - b2 ** t)
-    Gto = m_hat / (jnp.sqrt(v_hat) + eps)
-    phi = jnp.linalg.norm(Gto, axis=0) / jnp.maximum(
-        jnp.linalg.norm(Gt, axis=0), 1e-30)
-    Lam = (G - jnp.matmul(S, Gt, precision=HIGHEST)) * phi[None, :]
-    lam = jnp.linalg.norm(Lam)
-    limit = zeta * lam_prev
-    clip = jnp.where((lam_prev > 0) & (lam > limit),
-                     limit / jnp.maximum(lam, 1e-30), 1.0)
-    lam_new = jnp.where(lam_prev > 0, jnp.minimum(lam, limit), lam)
-    upd = -lr * scale * (jnp.matmul(S, Gto, precision=HIGHEST) + clip * Lam)
-    upd = upd.T if g.shape[0] > g.shape[1] else upd
-    return (p + upd).astype(p.dtype), M, V, lam_new, jnp.linalg.norm(Gt)
-
-
-@partial(jax.jit, static_argnames=("hp",), donate_argnums=(0,))
-def _dense_step(g, cscale, p, M, V, t, lr, hp):
-    b1, b2, eps = hp[:3]
-    g = g * cscale
-    M = b1 * M + (1 - b1) * g
-    V = b2 * V + (1 - b2) * g * g
-    upd = -lr * (M / (1 - b1 ** t)) / (jnp.sqrt(V / (1 - b2 ** t)) + eps)
-    return (p + upd).astype(p.dtype), M, V, jnp.linalg.norm(g)
-
-
-def _sqnorm(tree) -> float:
-    return float(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(tree)))
-
-
-def _flat(tree, prefix=()):
-    if isinstance(tree, dict):
-        for k in sorted(tree):
-            yield from _flat(tree[k], prefix + (k,))
-    else:
-        yield prefix, tree
-
-
-def _set(tree, path, value):
-    for k in path[:-1]:
-        tree = tree[k]
-    tree[path[-1]] = value
-
-
-def _name(kind, i, path):
-    return ("layers." + ".".join(path) + f".{i}" if kind == "layer"
-            else ".".join(path))
-
-
-def train(params, batches, conf, opt, fmt=None, rows=None):
-    """Warm start on ``batches[0]`` and ``len(batches) - 1`` plain steps.
-
-    Returns the loss of each step, the first step's global gradient norm
-    (before the clip), the first clipped gradient's norm per slice as the
-    optimizer holds it (projected for low-rank slices), the first raw
-    gradient's norm per slice, each slice's parameter change after the
-    last step, and the names of the dense-Adam slices.  A slice is one layer's piece of a stacked leaf.
-    """
-    model = Model(conf, fmt)
-    top, layers = split_layers(params)
-    del params                       # the stacked copy: only the split stays
-    rank = opt["rank"]
-    init = opt["init"]
-    init = (init["method"],) + tuple(
-        init[k] for k in ("seed", "oversample", "power_iters") if k in init)
-    hp = (opt["beta1"], opt["beta2"], opt["eps"], opt["scale"], opt["zeta"])
-    lr, clip_norm = opt["lr"], opt["clip_norm"]
-
-    def owner(kind, i):
-        """The dict the piece's weights live in, and the piece's paths."""
-        return layers[i] if kind == "layer" else top
-
-    def pieces(gen):
-        for item in gen:
-            if item[0] == "loss":
-                continue
-            if item[0] == "layer":
-                yield "layer", item[1], item[2]
-            elif item[0] == "embed":
-                yield "embed", None, {"embed": item[1]}
-            else:
-                yield item[0], None, item[1]
-
-    state: dict = {}
-    for kind, i, g in pieces(model.grads(top, layers, batches[0], rows)):
-        for path, gs in _flat(g):
-            if is_lowrank(gs.shape, rank):
-                n = max(gs.shape)
-                state[_name(kind, i, path)] = [
-                    _warm(gs, rank, init), jnp.zeros((rank, n)),
-                    jnp.zeros((rank, n)), jnp.float32(0.0)]
-            else:
-                state[_name(kind, i, path)] = [
-                    None, jnp.zeros(gs.shape), jnp.zeros(gs.shape)]
-
-    P0, losses, first_grad, first_raw = {}, [], {}, {}
-    for t, tokens in enumerate(batches[1:], start=1):
-        sq, loss = 0.0, None
-        for item in model.grads(top, layers, tokens, rows):
-            if item[0] == "loss":
-                loss = item[1]
-            else:
-                sq += _sqnorm(item[-1])
-        losses.append(loss)
-        if t == 1:
-            gnorm = math.sqrt(sq)
-        cscale = min(1.0, clip_norm / max(math.sqrt(sq), 1e-12))
-        for kind, i, g in pieces(model.grads(top, layers, tokens, rows)):
-            home = owner(kind, i)
-            for path, gs in _flat(g):
-                name = _name(kind, i, path)
-                p = home
-                for k in path:
-                    p = p[k]
-                if t == 1:
-                    first_raw[name] = float(jnp.linalg.norm(gs))
-                    P0[name] = p
-                st = state[name]
-                args = (gs, jnp.float32(cscale), p)
-                gs = None
-                _set(g, path, None)          # the step takes the buffer
-                if st[0] is not None:
-                    p2, st[1], st[2], st[3], gn = _lowrank_step(
-                        *args, st[0], st[1], st[2], st[3],
-                        jnp.float32(t), jnp.float32(lr), hp)
-                else:
-                    p2, st[1], st[2], gn = _dense_step(
-                        *args, st[1], st[2], jnp.float32(t),
-                        jnp.float32(lr), hp)
-                del args
-                if t == 1:
-                    first_grad[name] = float(gn)
-                _set(home, path, p2)
-            del g, gs
-    change = {}
-    for name, p0 in P0.items():
-        path = tuple(name.split("."))
-        if path[0] == "layers":
-            p = layers[int(path[-1])]
-            path = path[1:-1]
-        else:
-            p = top
-        for k in path:
-            p = p[k]
-        change[name] = float(jnp.linalg.norm(
-            p.astype(jnp.float32) - p0.astype(jnp.float32)))
-    return {"losses": losses, "gnorm": gnorm, "first_grad": first_grad,
-            "first_raw": first_raw, "change": change,
-            "dense": {n for n, st in state.items() if st[0] is None}}
-
+        yield TOP, {"embed": _embed_grad(dx, tokens,
+                                         rows=top["embed"].shape[0])}
+        yield None, total

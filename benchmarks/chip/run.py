@@ -166,7 +166,8 @@ def per_layer(run) -> dict:
     """Read each per-layer metric of the cell from the trace and the
     harness's host records; a reader that finds nothing is left out."""
     rec = {"trace": run.trace_data, "host": run.host, "peaks": run.peaks,
-           "config": run.cell.config, "workload": run.cell.workload}
+           "config": run.cell.config, "workload": run.cell.workload,
+           "bench_dir": run.cell.bench_dir}
     out = {}
     for m in run.cell.per_layer:
         reader = bench.load_module(run.cell.bench_dir / "metrics" /

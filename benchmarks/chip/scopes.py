@@ -9,9 +9,11 @@ trace names its op events by instruction (``%fusion.287 = ...``) and
 carries no metadata.  So the compiled step's text is parsed into a map
 {instruction: (scope, pass)}, and each leaf op of the trace that ran inside
 an execution of the step's module is attributed through it.  The scope is
-the innermost of ``SCOPES`` on the path (a transform may wrap a component:
-``jvp(decoder)``); the pass is ``recompute`` under ``rematted_computation``,
-``backward`` under a ``transpose(...)`` transform, ``forward`` otherwise.
+the innermost of ``SCOPES``, and of the configuration's family's
+``EXTRA_SCOPES`` (``refs/<family>.py``), on the path (a transform may wrap
+a component: ``jvp(decoder)``); the pass is ``recompute`` under
+``rematted_computation``, ``backward`` under a ``transpose(...)``
+transform, ``forward`` otherwise.
 A fusion carries its root's ``op_name``, so a fusion across a boundary
 counts once, on its root's side.
 
@@ -28,8 +30,10 @@ import hashlib
 import re
 import sys
 import time
+from pathlib import Path
 
 from benchmarks.chip import bench
+from benchmarks.chip import model as model_lib
 from benchmarks.chip import trace as trace_lib
 
 SCOPES = ("embed", "decoder", "attention", "mlp", "head_loss", "clip",
@@ -47,8 +51,9 @@ _FRAME_SECTIONS = frozenset({"FileNames", "FunctionNames", "FileLocations",
                              "StackFrames"})
 
 
-def scope_of(op_name: str) -> tuple[str | None, str]:
-    """``op_name`` -> (innermost scope of ``SCOPES`` or None, pass)."""
+def scope_of(op_name: str, extra=()) -> tuple[str | None, str]:
+    """``op_name`` -> (innermost scope of ``SCOPES`` or of the family's
+    ``extra`` names, or None; pass)."""
     scope, backward, recompute = None, False, False
     for comp in op_name.split("/"):
         backward |= comp.startswith("transpose(")
@@ -57,13 +62,13 @@ def scope_of(op_name: str) -> tuple[str | None, str]:
         while m and m.group(1) not in ("jit", "pjit"):
             comp = m.group(2)
             m = _WRAP.match(comp)
-        if comp in SCOPES:
+        if comp in SCOPES or comp in extra:
             scope = comp
     return scope, ("recompute" if recompute else
                    "backward" if backward else "forward")
 
 
-def op_map(hlo_text: str) -> dict:
+def op_map(hlo_text: str, extra=()) -> dict:
     """A compiled module's text -> {"module": name, "ops": {instruction:
     (scope, pass)}}.  An instruction that the compiler added without an
     ``op_name`` (a layout copy, an asynchronous copy's start and done)
@@ -76,7 +81,7 @@ def op_map(hlo_text: str) -> dict:
         if not m:
             continue
         name, op_name = m.group(1), _OP_NAME.search(line)
-        ops[name] = scope_of(op_name.group(1)) if op_name else None
+        ops[name] = scope_of(op_name.group(1), extra) if op_name else None
         operands[name] = [r for r in _REF.findall(line[m.end():])
                           if r != name]
         for r in operands[name]:
@@ -137,22 +142,24 @@ def strip_metadata(hlo_text: str) -> str:
     return "\n".join(out)
 
 
-def compiled_step(conf: dict, wl: dict) -> str:
-    """The optimized HLO text of the train step a training cell runs,
-    compiled from shapes alone: the state as a step leaves it (replicated
-    over the program's one-device mesh), the batch and the learning rate
-    as the window feeds them (placed on no device in particular)."""
+def compiled_step(conf: dict, wl: dict,
+                  bench_dir: Path = bench.BENCH_DIR) -> str:
+    """The optimized HLO text of the train step a training cell runs, built
+    by the cell's own kind (``kinds/<kind>.py``) and compiled from shapes
+    alone: the state as a step leaves it (replicated over the program's
+    one-device mesh), the batch and the learning rate as the window feeds
+    them (placed on no device in particular)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from benchmarks.chip import model as model_lib
     from repro.distributed.context import get_mesh_context
     from repro.launch.steps import TrainState
 
-    kind = bench.load_module(bench.BENCH_DIR / "kinds" / "train.py")
-    prog = kind.Program(conf, wl)
-    params = jax.eval_shape(lambda: model_lib.make_params(conf, 0))
+    kind = bench.load_module(bench_dir / "kinds" / f"{wl['kind']}.py")
+    prog = kind.Program(conf, wl, bench_dir)
+    params = jax.eval_shape(lambda: model_lib.make_params(conf, 0,
+                                                          bench_dir))
     state = TrainState(params=params,
                        opt=jax.eval_shape(prog.init_opt, params))
     free = lambda t: jax.tree.map(  # noqa: E731
@@ -175,8 +182,10 @@ def step_op_map(rec) -> dict:
     host = rec["host"]
     if "step_ops" not in host:
         t0 = time.perf_counter()
-        text = compiled_step(rec["config"], rec["workload"])
-        host["step_ops"] = op_map(text)
+        conf, bench_dir = rec["config"], rec["bench_dir"]
+        extra = getattr(model_lib.family(conf, bench_dir), "EXTRA_SCOPES", ())
+        text = compiled_step(conf, rec["workload"], bench_dir)
+        host["step_ops"] = op_map(text, tuple(extra))
         print(f"[scopes] {host['step_ops']['module']} compiled again in "
               f"{time.perf_counter() - t0:.1f}s; without metadata sha256 "
               f"{hashlib.sha256(strip_metadata(text).encode()).hexdigest()}",
@@ -249,7 +258,8 @@ def _report(sp: dict, steps: int, rec) -> None:
           file=err)
     print("[scopes] ms per step  " + "".join(f"{p:>11}" for p in PASSES),
           file=err)
-    for scope in SCOPES + (None,):
+    extra = sorted({s for s, _ in sp["by"] if s is not None} - set(SCOPES))
+    for scope in SCOPES + tuple(extra) + (None,):
         row = [sp["by"].get((scope, p), 0.0) for p in PASSES]
         if any(row):
             print(f"[scopes] {str(scope):12s}" + "".join(
@@ -285,12 +295,13 @@ def reading(rec) -> dict | None:
     return host["step_split"]
 
 
-def layer_ms(rec, names, passes=PASSES) -> float | None:
+def layer_ms(rec, names=None, passes=PASSES) -> float | None:
     """Device milliseconds per step of the window in the scopes ``names``
-    (None names the ops in no scope) and ``passes``."""
+    (None in it names the ops in no scope; ``names`` None counts every op
+    of the map) and ``passes``."""
     sp = reading(rec)
     if sp is None:
         return None
     seconds = sum(v for (s, p), v in sp["by"].items()
-                  if s in names and p in passes)
+                  if (names is None or s in names) and p in passes)
     return 1e3 * seconds / rec["host"]["steps"]

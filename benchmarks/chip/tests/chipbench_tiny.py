@@ -32,7 +32,8 @@ def _conf(name, **kw):
             "intermediate_size": 256, "num_attention_heads": 4,
             "num_key_value_heads": 4, "num_hidden_layers": 2,
             "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
-            "tie_word_embeddings": False, "reduced": [], "assumed": []}
+            "tie_word_embeddings": False, "reduced": [], "assumed": [],
+            "reference": "decoder"}
     base.update(kw)
     return base
 

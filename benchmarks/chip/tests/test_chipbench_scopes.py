@@ -206,6 +206,20 @@ def test_readers_on_a_recorded_tpu_trace(recorded):
     assert got["optimizer_ms.train"] > 0 and got["recompute_ms.train"] > 0
 
 
+def test_recorded_readings_are_pinned(recorded):
+    """The readers on the recorded trace read what they read before a
+    family could add scope names."""
+    tr, step_ops = recorded
+    rec = _rec(tr, step_ops, step_ops["steps"], step_ops["rank"])
+    assert {n: _reader(n).read(rec) for n in READERS} == {
+        "attention_ms.train": 0.02328699999999337,
+        "mlp_ms.train": 0.00525800000000104,
+        "embed_head_ms.train": 0.006856000000002999,
+        "layer_scan_ms.train": 0.003746499999970898,
+        "optimizer_ms.train": 0.03224499999998978,
+        "recompute_ms.train": 0.00679500000001082}
+
+
 def test_recorded_split_adds_up_to_the_step_module(recorded):
     tr, step_ops = recorded
     sp = scopes.split(tr, step_ops)
@@ -235,6 +249,7 @@ def test_compiled_again_is_the_step_that_ran(tmp_path, monkeypatch):
     batch = prog.data(11)
     state, lr, _ = prog.start(11, batch)
     ran = prog.step.lower(state, batch(4), lr).compile().as_text()
-    again = scopes.compiled_step(cell.config, cell.workload)
+    again = scopes.compiled_step(cell.config, cell.workload,
+                                 root / "benchmarks/chip")
     assert scopes.op_map(again) == scopes.op_map(ran)
     assert scopes.strip_metadata(again) == scopes.strip_metadata(ran)
